@@ -30,6 +30,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import kernel_bench, paper_tables
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     t0 = time.perf_counter()
     rows = ["name,us_per_call,derived"]

@@ -4,8 +4,10 @@ PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CoaddEngine, CoaddQuery, SurveyConfig, make_survey
 
+enable_compile_cache()
 survey = make_survey(SurveyConfig(n_runs=6, n_fields=8, n_sources=200,
                                   height=24, width=24))
 print(f"survey: {len(survey)} CCD frames "

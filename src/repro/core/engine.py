@@ -123,7 +123,6 @@ from repro.core.survey import Survey
 from repro.distributed.sharding import (
     shard_count,
     shard_local_compaction,
-    shard_map_compat,
 )
 from repro.kernels.warp import ops as warp_ops
 
@@ -261,14 +260,32 @@ def _accept_from_meta(ints, floats, qvec):
     return band_ok & valid & ra_ok & dec_ok & t_ok
 
 
+def _map_reduce(pixels, wcs, accept, grid_ra, grid_dec, psf_kernels,
+                use_kernel, block_rows=None):
+    """Map + local reduce of one (N, H, W) batch -> (coadd, depth).
+
+    The Pallas lane fuses both stages in `coadd_fused` (the projected tiles
+    never leave VMEM); the XLA lane projects with `mapper.map_batch` and
+    sums the tile stack.
+    """
+    if use_kernel:
+        return warp_ops.coadd_fused(
+            pixels, wcs, accept.astype(jnp.float32), grid_ra, grid_dec,
+            psf_kernels=psf_kernels, block_rows=block_rows,
+        )
+    tiles, covs = mapper.map_batch(
+        pixels, wcs, accept, grid_ra, grid_dec, psf_kernels=psf_kernels
+    )
+    return reducer.reduce_local(tiles, covs)
+
+
 @partial(jax.jit, static_argnames=("use_kernel",))
 def _coadd_batch(pixels, wcs, ints, floats, qvec, grid_ra, grid_dec, use_kernel=False):
     """Map+local-reduce one dense batch of images. The jitted inner job."""
     accept = _accept_from_meta(ints, floats, qvec)
-    tiles, covs = mapper.map_batch(
-        pixels, wcs, accept, grid_ra, grid_dec, use_kernel=use_kernel
+    coadd, depth = _map_reduce(
+        pixels, wcs, accept, grid_ra, grid_dec, None, use_kernel
     )
-    coadd, depth = reducer.reduce_local(tiles, covs)
     return coadd, depth, accept.sum()
 
 
@@ -284,7 +301,6 @@ def _scan_coadd(
     grid_dec,     # (Q, Q)
     use_kernel,
     block_rows,
-    interpret,
     pack_idx=None,  # (G,) int32 — sparse: scan only these packs of the layout
 ):
     """The whole query in ONE XLA program: scan packs, fuse map+reduce.
@@ -308,22 +324,8 @@ def _scan_coadd(
     def body(carry, px, wv, ints_p, floats_p, kern_p, gate_p):
         coadd, depth, contrib = carry
         accept = _accept_from_meta(ints_p, floats_p, qvec) & gate_p
-        if use_kernel:
-            c, d = warp_ops.coadd_fused(
-                px,
-                wv,
-                accept.astype(jnp.float32),
-                grid_ra,
-                grid_dec,
-                psf_kernels=kern_p,
-                block_rows=block_rows,
-                interpret=interpret,
-            )
-        else:
-            tiles, covs = mapper.map_batch(
-                px, wv, accept, grid_ra, grid_dec, psf_kernels=kern_p
-            )
-            c, d = reducer.reduce_local(tiles, covs)
+        c, d = _map_reduce(px, wv, accept, grid_ra, grid_dec, kern_p,
+                           use_kernel, block_rows)
         return (coadd + c, depth + d, contrib + accept.sum()), None
 
     q = grid_ra.shape[0]
@@ -371,22 +373,22 @@ def _scan_packs(body, init, pixels, wcs, ints, floats, psf_kernels, gate,
     return jax.lax.scan(step, init, xs)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _coadd_scan(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    use_kernel=False, block_rows=8, interpret=True,
+    use_kernel=False, block_rows=None,
 ):
     """One plan against a device-resident layout, as one jitted program."""
     return _scan_coadd(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        use_kernel, block_rows, interpret,
+        use_kernel, block_rows,
     )
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _coadd_scan_batch(
     pixels, wcs, ints, floats, psf_kernels, gates, qvecs, grids_ra, grids_dec,
-    use_kernel=False, block_rows=8, interpret=True,
+    use_kernel=False, block_rows=None,
 ):
     """K stacked plans against one resident layout, as ONE jitted program.
 
@@ -398,16 +400,16 @@ def _coadd_scan_batch(
     def one(gate, qvec, grid_ra, grid_dec):
         return _scan_coadd(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, use_kernel, block_rows, interpret,
+            grid_dec, use_kernel, block_rows,
         )
 
     return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _coadd_scan_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gate, qvec, grid_ra,
-    grid_dec, use_kernel=False, block_rows=8, interpret=True,
+    grid_dec, use_kernel=False, block_rows=None,
 ):
     """Sparse plan against a resident layout, still ONE jitted program.
 
@@ -419,14 +421,14 @@ def _coadd_scan_sparse(
     """
     return _scan_coadd(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        use_kernel, block_rows, interpret, pack_idx=pack_idx,
+        use_kernel, block_rows, pack_idx=pack_idx,
     )
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _coadd_scan_batch_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gates, qvecs, grids_ra,
-    grids_dec, use_kernel=False, block_rows=8, interpret=True,
+    grids_dec, use_kernel=False, block_rows=None,
 ):
     """K stacked plans over the union of their gated packs, ONE program.
 
@@ -440,7 +442,7 @@ def _coadd_scan_batch_sparse(
     def one(gate, qvec, grid_ra, grid_dec):
         return _scan_coadd(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, use_kernel, block_rows, interpret, pack_idx=pack_idx,
+            grid_dec, use_kernel, block_rows, pack_idx=pack_idx,
         )
 
     return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec)
@@ -457,7 +459,7 @@ def _coadd_scan_batch_sparse(
 
 def _scan_moments(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    use_kernel, block_rows, interpret, pack_idx=None,
+    use_kernel, block_rows, pack_idx=None,
 ):
     """Robust pass 1: coverage-weighted moments of the stack, ONE program."""
 
@@ -467,7 +469,7 @@ def _scan_moments(
         if use_kernel:
             a0, a1, a2 = warp_ops.coadd_moments(
                 px, wv, accept.astype(jnp.float32), grid_ra, grid_dec,
-                psf_kernels=kern_p, block_rows=block_rows, interpret=interpret,
+                psf_kernels=kern_p, block_rows=block_rows,
             )
         else:
             tiles, covs = mapper.map_batch(
@@ -487,7 +489,7 @@ def _scan_moments(
 
 def _scan_hist(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    lo, inv_w, nbins, use_kernel, block_rows, interpret, pack_idx=None,
+    lo, inv_w, nbins, use_kernel, block_rows, pack_idx=None,
 ):
     """Median round 1: coverage-weighted binapprox histogram, ONE program."""
 
@@ -497,7 +499,7 @@ def _scan_hist(
             h = warp_ops.coadd_hist(
                 px, wv, accept.astype(jnp.float32), grid_ra, grid_dec,
                 lo, inv_w, nbins=nbins, psf_kernels=kern_p,
-                block_rows=block_rows, interpret=interpret,
+                block_rows=block_rows,
             )
         else:
             tiles, covs = mapper.map_batch(
@@ -515,7 +517,7 @@ def _scan_hist(
 
 def _scan_clip(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    center, thresh, use_kernel, block_rows, interpret, pack_idx=None,
+    center, thresh, use_kernel, block_rows, pack_idx=None,
 ):
     """Robust final pass: accumulate only samples inside the clip window."""
 
@@ -526,7 +528,7 @@ def _scan_clip(
             c, d = warp_ops.coadd_clip(
                 px, wv, accept.astype(jnp.float32), grid_ra, grid_dec,
                 center, thresh, psf_kernels=kern_p,
-                block_rows=block_rows, interpret=interpret,
+                block_rows=block_rows,
             )
         else:
             tiles, covs = mapper.map_batch(
@@ -544,7 +546,7 @@ def _scan_clip(
 
 def _robust_passes(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    clip_k, use_kernel, block_rows, interpret, reduce, median_bins,
+    clip_k, use_kernel, block_rows, reduce, median_bins,
     pack_idx=None,
 ):
     """All robust passes composed in one traceable program (the eager path).
@@ -593,7 +595,7 @@ def _robust_passes(
 
     s0, s1, s2, contrib, considered = _scan_moments(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        use_kernel, block_rows, interpret, pack_idx=pack_idx,
+        use_kernel, block_rows, pack_idx=pack_idx,
     )
     mu, sigma = reducer.clip_stats(s0, s1, s2)
     if reduce == "median":
@@ -601,7 +603,7 @@ def _robust_passes(
         hist = _scan_hist(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
             grid_dec, lo, inv_w, median_bins, use_kernel, block_rows,
-            interpret, pack_idx=pack_idx,
+            pack_idx=pack_idx,
         )
         center = reducer.hist_median(hist, s0, lo, w)
     else:
@@ -609,31 +611,31 @@ def _robust_passes(
     thresh = reducer.clip_threshold(center, sigma, clip_k)
     coadd, depth = _scan_clip(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        center, thresh, use_kernel, block_rows, interpret, pack_idx=pack_idx,
+        center, thresh, use_kernel, block_rows, pack_idx=pack_idx,
     )
     return coadd, depth, contrib, considered
 
 
 @partial(jax.jit, static_argnames=(
-    "use_kernel", "block_rows", "interpret", "reduce", "median_bins"))
+    "use_kernel", "block_rows", "reduce", "median_bins"))
 def _robust_scan(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    clip_k, use_kernel=False, block_rows=8, interpret=True,
+    clip_k, use_kernel=False, block_rows=None,
     reduce="clipped", median_bins=16, pack_idx=None,
 ):
     """One robust plan against a resident layout — still ONE dispatch."""
     return _robust_passes(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        clip_k, use_kernel, block_rows, interpret, reduce, median_bins,
+        clip_k, use_kernel, block_rows, reduce, median_bins,
         pack_idx=pack_idx,
     )
 
 
 @partial(jax.jit, static_argnames=(
-    "use_kernel", "block_rows", "interpret", "reduce", "median_bins"))
+    "use_kernel", "block_rows", "reduce", "median_bins"))
 def _robust_scan_batch(
     pixels, wcs, ints, floats, psf_kernels, gates, qvecs, grids_ra, grids_dec,
-    clip_k, use_kernel=False, block_rows=8, interpret=True,
+    clip_k, use_kernel=False, block_rows=None,
     reduce="clipped", median_bins=16, pack_idx=None,
 ):
     """K stacked robust plans, ONE dispatch (shared sparse index, like
@@ -642,7 +644,7 @@ def _robust_scan_batch(
     def one(gate, qvec, grid_ra, grid_dec):
         return _robust_passes(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, clip_k, use_kernel, block_rows, interpret, reduce,
+            grid_dec, clip_k, use_kernel, block_rows, reduce,
             median_bins, pack_idx=pack_idx,
         )
 
@@ -651,84 +653,79 @@ def _robust_scan_batch(
 
 # Streaming per-pass entry points: one jitted dispatch per (window, pass),
 # returning additive partial tuples the WindowTracker can journal/resume.
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _moments_scan_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gate, qvec,
-    grid_ra, grid_dec, use_kernel=False, block_rows=8, interpret=True,
+    grid_ra, grid_dec, use_kernel=False, block_rows=None,
 ):
     return _scan_moments(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        use_kernel, block_rows, interpret, pack_idx=pack_idx,
+        use_kernel, block_rows, pack_idx=pack_idx,
     )
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret",
-                                   "nbins"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "nbins"))
 def _hist_scan_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gate, qvec,
-    grid_ra, grid_dec, lo, inv_w, nbins=16, use_kernel=False, block_rows=8,
-    interpret=True,
+    grid_ra, grid_dec, lo, inv_w, nbins=16, use_kernel=False, block_rows=None,
 ):
     return (_scan_hist(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        lo, inv_w, nbins, use_kernel, block_rows, interpret,
+        lo, inv_w, nbins, use_kernel, block_rows,
         pack_idx=pack_idx,
     ),)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _clip_scan_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gate, qvec,
-    grid_ra, grid_dec, center, thresh, use_kernel=False, block_rows=8,
-    interpret=True,
+    grid_ra, grid_dec, center, thresh, use_kernel=False, block_rows=None,
 ):
     return _scan_clip(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        center, thresh, use_kernel, block_rows, interpret, pack_idx=pack_idx,
+        center, thresh, use_kernel, block_rows, pack_idx=pack_idx,
     )
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _moments_scan_batch_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gates, qvecs,
-    grids_ra, grids_dec, use_kernel=False, block_rows=8, interpret=True,
+    grids_ra, grids_dec, use_kernel=False, block_rows=None,
 ):
     def one(gate, qvec, grid_ra, grid_dec):
         return _scan_moments(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, use_kernel, block_rows, interpret, pack_idx=pack_idx,
+            grid_dec, use_kernel, block_rows, pack_idx=pack_idx,
         )
 
     return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret",
-                                   "nbins"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "nbins"))
 def _hist_scan_batch_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gates, qvecs,
     grids_ra, grids_dec, los, inv_ws, nbins=16, use_kernel=False,
-    block_rows=8, interpret=True,
+    block_rows=None,
 ):
     def one(gate, qvec, grid_ra, grid_dec, lo, inv_w):
         return (_scan_hist(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, lo, inv_w, nbins, use_kernel, block_rows, interpret,
+            grid_dec, lo, inv_w, nbins, use_kernel, block_rows,
             pack_idx=pack_idx,
         ),)
 
     return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec, los, inv_ws)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
 def _clip_scan_batch_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gates, qvecs,
-    grids_ra, grids_dec, centers, threshs, use_kernel=False, block_rows=8,
-    interpret=True,
+    grids_ra, grids_dec, centers, threshs, use_kernel=False, block_rows=None,
 ):
     def one(gate, qvec, grid_ra, grid_dec, center, thresh):
         return _scan_clip(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, center, thresh, use_kernel, block_rows, interpret,
+            grid_dec, center, thresh, use_kernel, block_rows,
             pack_idx=pack_idx,
         )
 
@@ -771,9 +768,8 @@ def _match_packs(pixels, kernels):
     )
 
 
-@partial(jax.jit, static_argnames=("npix", "use_kernel", "interpret"))
-def _mosaic_bricks(tiles, covs, offsets, npix, use_kernel=False,
-                   interpret=True):
+@partial(jax.jit, static_argnames=("npix", "use_kernel"))
+def _mosaic_bricks(tiles, covs, offsets, npix, use_kernel=False):
     """Merge cached brick tiles into one (npix, npix) mosaic (DESIGN.md §9).
 
     One jitted dispatch over (B, b, b) device-resident brick coadds +
@@ -782,9 +778,7 @@ def _mosaic_bricks(tiles, covs, offsets, npix, use_kernel=False,
     so both match the fresh lattice-window scan bitwise.
     """
     if use_kernel:
-        return warp_ops.mosaic_bricks(
-            tiles, covs, offsets, npix, interpret=interpret
-        )
+        return warp_ops.mosaic_bricks(tiles, covs, offsets, npix)
     return reducer.mosaic_tiles(tiles, covs, offsets, npix)
 
 
@@ -807,9 +801,9 @@ class CoaddEngine:
     host->mesh exactly once per (layout, mesh) (`mesh_dataset`); every query
     — single, batched, or distributed — is a single jitted dispatch.  Set
     ``use_kernel=True`` to fuse map+reduce through the Pallas ``coadd_fused``
-    kernel (``kernel_interpret=False`` on real TPUs lowers through Mosaic),
-    and ``match_psf_sigma`` to convolve every image to a common PSF width in
-    the map stage before warping.
+    kernel (lowered through Mosaic on a TPU, interpreted on the CPU; see
+    `repro.kernels.interpret_mode`), and ``match_psf_sigma`` to convolve
+    every image to a common PSF width in the map stage before warping.
     """
 
     def __init__(
@@ -818,7 +812,6 @@ class CoaddEngine:
         pack_capacity: int = 64,
         use_kernel: bool = False,
         block_rows: Optional[int] = None,
-        kernel_interpret: bool = True,
         match_psf_sigma: Optional[float] = None,
         measured_psf: Optional[bool] = None,
         matched_pixel_cache: bool = True,
@@ -847,7 +840,6 @@ class CoaddEngine:
         self.median_bins = int(median_bins)
         self.use_kernel = use_kernel
         self.block_rows = block_rows  # None -> autotune per (npix, H, W)
-        self.kernel_interpret = kernel_interpret
         self.match_psf_sigma = match_psf_sigma
         # Measured-PSF homogenization (DESIGN.md §7): None = auto (use the
         # survey's empirical stamps when present, separable Gaussian bank
@@ -1290,11 +1282,17 @@ class CoaddEngine:
         """
         return grid_digest(plan.grid_sky)
 
-    def _block_rows(self, query: CoaddQuery, ds: PackedDataset) -> int:
-        if self.block_rows is not None:
+    def _block_rows(self, query: CoaddQuery, ds: PackedDataset) -> Optional[int]:
+        """The Pallas lane's output block (None on the XLA lane).
+
+        Raises ``ValueError`` before any dispatch when the kernel lane is
+        asked to warp frames too large for one VMEM grid step: the engine
+        refuses such a query rather than falling back to the XLA lane.
+        """
+        if not self.use_kernel or self.block_rows is not None:
             return self.block_rows
         h, w = ds.image_hw()
-        bank = self.psf_kernel_bank(ds.layout) if self.use_kernel else None
+        bank = self.psf_kernel_bank(ds.layout)
         return warp_ops.autotune_block_rows(
             query.npix, h, w,
             psf_kernel_width=0 if bank is None else bank.shape[-1],
@@ -1654,7 +1652,6 @@ class CoaddEngine:
                 grid_dec,
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
-                interpret=self.kernel_interpret,
             )
 
         job_key = self._job_key(plan.method, plan.layout, gate, plan.qvec,
@@ -1756,7 +1753,6 @@ class CoaddEngine:
                     jnp.asarray(compact_window_gate(g, win)),
                     qvec, grid_ra, grid_dec, *extra,
                     use_kernel=self.use_kernel, block_rows=block_rows,
-                    interpret=self.kernel_interpret,
                 )
 
             # Computed per pass, not once: a quarantine during an earlier
@@ -1850,78 +1846,13 @@ class CoaddEngine:
             if plan.reduce != "mean":
                 return self._execute_streaming_robust(plan)
             return self._execute_streaming(plan)
-        ds = self.dataset(plan.layout)
         exec_ds, _ = self.exec_dataset(plan.layout)
-        dev = self.device_dataset(plan.layout)
         gate = self._exec_gate(plan)
-        grid_ra, grid_dec = self._plan_grids(plan)
-        block_rows = self._block_rows(plan.query, ds)
-        psf_kernels = self._device_psf_kernels(plan.layout)
-        m_builds0, m_hits = self.matched_builds, 0
-        if self._matched_mode():
-            # §7: the dispatch reads pre-matched resident pixels; no bank
-            # operand, no per-query convolution.
-            dev, m_hits = self._matched_device_dataset(plan.layout, dev)
-            psf_kernels = None
-        sp = self._sparse_index(gate)
+        m_builds0 = self.matched_builds
+        fn, args, kwargs, sp, m_hits = self._eager_program(plan)
         t1 = time.perf_counter()
         self.dispatch_count += 1
-        if plan.reduce != "mean":
-            # Robust eager path: all passes fused into ONE jitted dispatch
-            # (the in-program re-scan is what keeps clipped within the
-            # perf-gate overhead budget vs the mean).
-            gate_dev = (jnp.asarray(compact_gate(gate, sp)) if sp is not None
-                        else jnp.asarray(gate))
-            pack_idx = jnp.asarray(sp.pack_idx) if sp is not None else None
-            coadd, depth, contrib, considered = _robust_scan(
-                dev.pixels,
-                dev.wcs,
-                dev.ints,
-                dev.floats,
-                psf_kernels,
-                gate_dev,
-                jnp.asarray(plan.qvec),
-                grid_ra,
-                grid_dec,
-                jnp.float32(self.clip_k),
-                use_kernel=self.use_kernel,
-                block_rows=block_rows,
-                interpret=self.kernel_interpret,
-                reduce=plan.reduce,
-                median_bins=self.median_bins,
-                pack_idx=pack_idx,
-            )
-        elif sp is not None:
-            coadd, depth, contrib, considered = _coadd_scan_sparse(
-                dev.pixels,
-                dev.wcs,
-                dev.ints,
-                dev.floats,
-                psf_kernels,
-                jnp.asarray(sp.pack_idx),
-                jnp.asarray(compact_gate(gate, sp)),
-                jnp.asarray(plan.qvec),
-                grid_ra,
-                grid_dec,
-                use_kernel=self.use_kernel,
-                block_rows=block_rows,
-                interpret=self.kernel_interpret,
-            )
-        else:
-            coadd, depth, contrib, considered = _coadd_scan(
-                dev.pixels,
-                dev.wcs,
-                dev.ints,
-                dev.floats,
-                psf_kernels,
-                jnp.asarray(gate),
-                jnp.asarray(plan.qvec),
-                grid_ra,
-                grid_dec,
-                use_kernel=self.use_kernel,
-                block_rows=block_rows,
-                interpret=self.kernel_interpret,
-            )
+        coadd, depth, contrib, considered = fn(*args, **kwargs)
         coadd.block_until_ready()
         t2 = time.perf_counter()
         scanned = sp.budget if sp is not None else exec_ds.n_packs
@@ -1943,6 +1874,70 @@ class CoaddEngine:
             reduce=plan.reduce,
         )
         return CoaddResult(np.asarray(coadd), np.asarray(depth), stats)
+
+    def _eager_program(self, plan: CoaddPlan):
+        """The jitted program and operands `execute` dispatches for a plan
+        against the eager resident layout.
+
+        Returns ``(fn, args, kwargs, sparse_index, matched_cache_hits)``;
+        the call returns ``(coadd, depth, contributing, considered)``.
+        Uploads the layout (and builds the matched-pixel cache) on first
+        use, exactly as the dispatch itself would need.
+        """
+        ds = self.dataset(plan.layout)
+        # Before the upload: a kernel-lane query whose frames cannot fit
+        # VMEM is refused here, not after moving the archive to the device.
+        block_rows = self._block_rows(plan.query, ds)
+        dev = self.device_dataset(plan.layout)
+        gate = self._exec_gate(plan)
+        grid_ra, grid_dec = self._plan_grids(plan)
+        psf_kernels = self._device_psf_kernels(plan.layout)
+        m_hits = 0
+        if self._matched_mode():
+            # §7: the dispatch reads pre-matched resident pixels; no bank
+            # operand, no per-query convolution.
+            dev, m_hits = self._matched_device_dataset(plan.layout, dev)
+            psf_kernels = None
+        sp = self._sparse_index(gate)
+        operands = (dev.pixels, dev.wcs, dev.ints, dev.floats, psf_kernels)
+        kwargs = dict(use_kernel=self.use_kernel, block_rows=block_rows)
+        if plan.reduce != "mean":
+            # Robust eager path: all passes fused into ONE jitted dispatch
+            # (the in-program re-scan is what keeps clipped within the
+            # perf-gate overhead budget vs the mean).
+            gate_dev = (jnp.asarray(compact_gate(gate, sp)) if sp is not None
+                        else jnp.asarray(gate))
+            kwargs.update(
+                reduce=plan.reduce,
+                median_bins=self.median_bins,
+                pack_idx=jnp.asarray(sp.pack_idx) if sp is not None else None,
+            )
+            args = operands + (gate_dev, jnp.asarray(plan.qvec), grid_ra,
+                               grid_dec, jnp.float32(self.clip_k))
+            return _robust_scan, args, kwargs, sp, m_hits
+        if sp is not None:
+            args = operands + (jnp.asarray(sp.pack_idx),
+                               jnp.asarray(compact_gate(gate, sp)),
+                               jnp.asarray(plan.qvec), grid_ra, grid_dec)
+            return _coadd_scan_sparse, args, kwargs, sp, m_hits
+        args = operands + (jnp.asarray(gate), jnp.asarray(plan.qvec),
+                           grid_ra, grid_dec)
+        return _coadd_scan, args, kwargs, sp, m_hits
+
+    def lower(self, plan: CoaddPlan) -> "jax.stages.Lowered":
+        """The program `execute(plan)` dispatches, lowered but not run.
+
+        ``lower(plan).compile().as_text()`` is what the device executes —
+        e.g. whether the Pallas lane became a Mosaic ``tpu_custom_call``.
+        Covers the eager path; a streaming engine dispatches one program
+        per window and raises here.
+        """
+        if self.device_budget_bytes is not None:
+            raise ValueError("lower() covers the eager path; this engine "
+                             "streams under a device budget")
+        self._check_plan_psf(plan)
+        fn, args, kwargs, _, _ = self._eager_program(plan)
+        return fn.lower(*args, **kwargs)
 
     def _eager_resident_bytes(self) -> int:
         """Device bytes resident *outside* the ResidencyManager: the eager
@@ -2184,7 +2179,6 @@ class CoaddEngine:
             jnp.asarray(np.array(offsets, np.int32)),
             query.npix,
             use_kernel=self.use_kernel,
-            interpret=self.kernel_interpret,
         )
         coadd.block_until_ready()
         t2 = time.perf_counter()
@@ -2347,7 +2341,6 @@ class CoaddEngine:
                 jnp.float32(self.clip_k),
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
-                interpret=self.kernel_interpret,
                 reduce=plans[0].reduce,
                 median_bins=self.median_bins,
                 pack_idx=pack_idx,
@@ -2366,7 +2359,6 @@ class CoaddEngine:
                 grids_dec,
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
-                interpret=self.kernel_interpret,
             )
         else:
             coadds, depths, contribs, considered = _coadd_scan_batch(
@@ -2381,7 +2373,6 @@ class CoaddEngine:
                 grids_dec,
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
-                interpret=self.kernel_interpret,
             )
         coadds.block_until_ready()
         t2 = time.perf_counter()
@@ -2463,7 +2454,6 @@ class CoaddEngine:
                 grids_dec,
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
-                interpret=self.kernel_interpret,
             )
 
         job_key = self._job_key(
@@ -2556,7 +2546,6 @@ class CoaddEngine:
                     jnp.asarray(compact_window_gates(g, win)),
                     qvecs_j, grids_ra, grids_dec, *extra,
                     use_kernel=self.use_kernel, block_rows=block_rows,
-                    interpret=self.kernel_interpret,
                 )
 
             job_key = self._job_key(
@@ -2754,7 +2743,6 @@ class CoaddEngine:
         meta_keys_i = tuple(sorted(exec_ds.ints.keys()))
         meta_keys_f = tuple(sorted(exec_ds.floats.keys()))
         use_kernel = self.use_kernel
-        interpret = self.kernel_interpret
         in_spec = P(shard_axes)
         out_rows = P(None, model_axis) if model_axis else P(None)
 
@@ -2783,12 +2771,10 @@ class CoaddEngine:
                     # Dense fallback: map the whole resident slab.
                     def one_query(gate, qvec, grid):
                         accept = _accept_from_meta(ints, floats, qvec) & gate
-                        tiles, covs = mapper.map_batch(
-                            px, wv, accept, grid[0], grid[1],
-                            use_kernel=use_kernel, block_rows=block_rows,
-                            interpret=interpret, psf_kernels=kern,
-                        )
-                        return collect(*reducer.reduce_local(tiles, covs))
+                        return collect(*_map_reduce(
+                            px, wv, accept, grid[0], grid[1], kern,
+                            use_kernel, block_rows,
+                        ))
 
                     return jax.vmap(one_query)(gates, qvecs, grids)
 
@@ -2820,12 +2806,10 @@ class CoaddEngine:
 
                     def one_query(gate, qvec, grid):
                         accept = _accept_from_meta(ints_t, floats_t, qvec) & gate
-                        tiles, covs = mapper.map_batch(
-                            px_t, wv_t, accept, grid[0], grid[1],
-                            use_kernel=use_kernel, block_rows=block_rows,
-                            interpret=interpret, psf_kernels=kern_tile,
+                        return _map_reduce(
+                            px_t, wv_t, accept, grid[0], grid[1], kern_tile,
+                            use_kernel, block_rows,
                         )
-                        return reducer.reduce_local(tiles, covs)
 
                     c, d = jax.vmap(one_query)(gates_t, qvecs, grids)
                     return (c_acc + c, d_acc + d)
@@ -2838,9 +2822,9 @@ class CoaddEngine:
                 c, d = jax.lax.fori_loop(0, n_tiles, tile_step, init)
                 return jax.vmap(collect)(c, d)
 
-            # vmap-of-psum under the VMA/rep checker is broken across jax
-            # versions (psum_invariant rejects axis_index_groups); check=False.
-            shard = shard_map_compat(
+            # vmap-of-psum fails the VMA checker (psum_invariant rejects
+            # axis_index_groups), so the check is off.
+            shard = jax.shard_map(
                 job,
                 mesh=mesh,
                 in_specs=(
@@ -2856,7 +2840,7 @@ class CoaddEngine:
                     P(None),
                 ),
                 out_specs=(out_rows, out_rows),
-                check=False,
+                check_vma=False,
             )
             self.dispatch_count += 1
             return shard(

@@ -9,10 +9,14 @@ contract:
 * a small **fault taxonomy** (`classify`) shared by the legacy `JobTracker`
   and the streaming `WindowTracker`: transient errors are retried with
   capped exponential backoff, fatal errors escape immediately.  The split is
-  deliberate policy, not exception pedigree — XLA surfaces device/transfer
-  failures as bare ``RuntimeError``, so that type is transient by default,
-  while `DeterminismError` (two executions of one task disagreeing) must
-  never be retried: re-running nondeterminism just rolls the dice again.
+  deliberate policy, not exception pedigree — a bare ``RuntimeError`` is
+  transient by default (the chaos drills raise it), but XLA's own
+  `jax.errors.JaxRuntimeError` is fatal unless its status says the device
+  or transfer was only unavailable: a program the compiler refuses, or
+  one that runs out of device memory (``RESOURCE_EXHAUSTED``), fails the
+  same way on every retry.  `DeterminismError` (two executions of one task
+  disagreeing) must never be retried: re-running nondeterminism just rolls
+  the dice again.
 
 * a **chaos harness** (`FaultSchedule` + `ChaosInjector`) that injects
   failures at the engine's *real* seams — `ResidencyManager` chunk uploads,
@@ -28,6 +32,7 @@ import dataclasses
 import time
 from typing import Dict, Iterable, Optional, Tuple
 
+import jax
 import numpy as np
 
 
@@ -65,9 +70,9 @@ class PoisonedChunkError(FaultError):
         super().__init__(f"poisoned packs {self.packs}: {reason}")
 
 
-# RuntimeError is transient by policy: XLA reports device/transfer errors as
-# RuntimeError, and so does the legacy FailureInjector.  FatalFault subclasses
-# (DeterminismError, QueryKilled) are checked first and always escape.
+# RuntimeError is transient by policy (the legacy FailureInjector raises
+# it).  FatalFault subclasses (DeterminismError, QueryKilled) and XLA errors
+# without a transient status are checked first and always escape.
 _TRANSIENT_TYPES = (
     TransientFault,
     ConnectionError,
@@ -76,6 +81,10 @@ _TRANSIENT_TYPES = (
     OSError,
     RuntimeError,
 )
+# XLA status codes of failures a retry can outlive (a lost device link, an
+# expired deadline); every other status — RESOURCE_EXHAUSTED, a compile or
+# lowering error (INTERNAL, INVALID_ARGUMENT, UNIMPLEMENTED) — is fatal.
+_TRANSIENT_XLA_STATUS = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED")
 
 
 def classify(exc: BaseException) -> str:
@@ -88,6 +97,9 @@ def classify(exc: BaseException) -> str:
     """
     if isinstance(exc, FatalFault):
         return "fatal"
+    if isinstance(exc, jax.errors.JaxRuntimeError):
+        status = str(exc).split(":", 1)[0].strip()
+        return "transient" if status in _TRANSIENT_XLA_STATUS else "fatal"
     if isinstance(exc, (PoisonedChunkError,) + _TRANSIENT_TYPES):
         return "transient"
     return "fatal"

@@ -82,20 +82,23 @@ def sky_to_tangent(ra, dec, ra0, dec0):
 
     Returns intermediate world coordinates (xi, eta) in **degrees** —
     the standard TAN "native" coordinates.
+
+    The RA offset is taken in degrees before the radian conversion: in
+    float32, ``ra*DEG2RAD - ra0*DEG2RAD`` at RA ~37 deg cancels to ~4e-8
+    rad of rounding (0.03 px on a 0.396"/px frame), while ``ra - ra0`` of
+    two nearby float32 values is exact.  The Pallas warp
+    (`kernels.warp.warp._sky_to_pixel`) mirrors these operations.
     """
     xp = jnp if isinstance(ra, jnp.ndarray) else np
-    ra_r = ra * DEG2RAD
+    dra = (ra - ra0) * DEG2RAD
     dec_r = dec * DEG2RAD
-    ra0_r = ra0 * DEG2RAD
     dec0_r = dec0 * DEG2RAD
-    cosc = xp.sin(dec0_r) * xp.sin(dec_r) + xp.cos(dec0_r) * xp.cos(dec_r) * xp.cos(
-        ra_r - ra0_r
-    )
-    xi = xp.cos(dec_r) * xp.sin(ra_r - ra0_r) / cosc
-    eta = (
-        xp.cos(dec0_r) * xp.sin(dec_r)
-        - xp.sin(dec0_r) * xp.cos(dec_r) * xp.cos(ra_r - ra0_r)
-    ) / cosc
+    sin_dec = xp.sin(dec_r)
+    cos_dec = xp.cos(dec_r)
+    cos_dra = xp.cos(dra)
+    cosc = xp.sin(dec0_r) * sin_dec + xp.cos(dec0_r) * cos_dec * cos_dra
+    xi = cos_dec * xp.sin(dra) / cosc
+    eta = (xp.cos(dec0_r) * sin_dec - xp.sin(dec0_r) * cos_dec * cos_dra) / cosc
     return xi * RAD2DEG, eta * RAD2DEG
 
 

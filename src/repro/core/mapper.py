@@ -71,8 +71,9 @@ def bilinear_sample(image: jnp.ndarray, sx: jnp.ndarray, sy: jnp.ndarray):
         + v10 * (1 - dx) * dy
         + v11 * dx * dy
     )
-    m = inside.astype(image.dtype)
-    return val * m, m
+    # A select, not val * mask: an empty pack slot (all-zero WCS) projects
+    # to NaN coordinates, and NaN * 0 is NaN on the TPU.
+    return jnp.where(inside, val, 0.0), inside.astype(image.dtype)
 
 
 def project_one(
@@ -153,9 +154,6 @@ def map_batch(
     accept: jnp.ndarray,     # (N,)
     grid_ra: jnp.ndarray,
     grid_dec: jnp.ndarray,
-    use_kernel: bool = False,
-    block_rows: int | None = None,
-    interpret: bool = True,
     psf_kernels: jnp.ndarray | None = None,  # (N, K) separable rows or
                                              # (N, K, K) measured-PSF taps
 ):
@@ -175,17 +173,6 @@ def map_batch(
         from repro.core import psf
 
         pixels = psf.convolve_batch(pixels, psf_kernels)
-    if use_kernel:
-        from repro.kernels.warp import ops as warp_ops
-
-        if block_rows is None:
-            block_rows = warp_ops.autotune_block_rows(
-                grid_ra.shape[0], pixels.shape[1], pixels.shape[2]
-            )
-        return warp_ops.warp_batch(
-            pixels, wcs_vecs, accept.astype(pixels.dtype), grid_ra, grid_dec,
-            block_rows=block_rows, interpret=interpret,
-        )
     return jax.vmap(project_one, in_axes=(0, 0, 0, None, None))(
         pixels, wcs_vecs, accept, grid_ra, grid_dec
     )

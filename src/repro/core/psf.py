@@ -86,7 +86,8 @@ def convolve_separable(image: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
     r = (kernel.shape[0] - 1) // 2
 
     def conv1d(row):
-        return jnp.convolve(jnp.pad(row, (r, r), mode="edge"), kernel, mode="valid")
+        return jnp.convolve(jnp.pad(row, (r, r), mode="edge"), kernel,
+                            mode="valid", precision=jax.lax.Precision.HIGHEST)
 
     out = jax.vmap(conv1d)(image)          # rows
     out = jax.vmap(conv1d)(out.T).T        # cols
@@ -274,7 +275,9 @@ def convolve_2d(image: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
     ``out[i, j] = sum_{m,n} kernel[m, n] * image[clip(i+m-r), clip(j+n-r)]``
     — edge padding makes the clip; `lax.conv_general_dilated` is already a
     cross-correlation, so the taps apply unflipped, exactly like the Pallas
-    2-D banded-matmul variant (`warp._convolve_2d_matmul`).
+    2-D banded-matmul variant (`warp._convolve_2d_matmul`).  HIGHEST
+    precision keeps the TPU from rounding pixels to bf16 in the MXU (its
+    default for float32), as in every other convolution here.
     """
     kh, kw = kernel.shape
     padded = jnp.pad(
@@ -285,5 +288,6 @@ def convolve_2d(image: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
         kernel[None, None].astype(jnp.float32),
         window_strides=(1, 1),
         padding="VALID",
+        precision=jax.lax.Precision.HIGHEST,
     )
     return out[0, 0]

@@ -94,38 +94,6 @@ def shard_local_compaction(
     return local_idx, pad_mask, budget, budgets
 
 
-# ------------------------------------------------------------- shard_map ---
-
-
-def shard_map_compat(f, mesh=None, in_specs=None, out_specs=None, check=True):
-    """`shard_map` across the jax API break.
-
-    jax >= 0.6 exposes top-level ``jax.shard_map`` (mesh optional, VMA check
-    named ``check_vma``); jax 0.4.x only has the experimental entry point
-    (mesh required, check named ``check_rep``).  ``mesh=None`` under the old
-    API resolves the active ``with mesh:`` context.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs, check_vma=check)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        from jax._src.mesh import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError(
-                "shard_map_compat(mesh=None) needs an active Mesh context "
-                "under jax<0.6"
-            )
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 # ---------------------------------------------------------------- params ---
 
 # name -> spec template for the *trailing* dims; leading (stacked-layer /

@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the hot spots the paper optimizes (`warp`)."""
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: on the CPU backend
+    only (tests), never on a TPU, where they must lower through Mosaic."""
+    return jax.default_backend() == "cpu"

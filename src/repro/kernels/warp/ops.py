@@ -1,8 +1,10 @@
 """Jitted public wrappers for the warp kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a real
-TPU deployment set ``repro.kernels.INTERPRET = False`` (or pass explicitly)
-and the same BlockSpecs lower through Mosaic.
+Interpret mode is never chosen by the caller: `repro.kernels.interpret_mode`
+turns it on for the CPU backend (the test environment) and off everywhere
+else, so on a TPU the same BlockSpecs always lower through Mosaic.
+``block_rows=None`` autotunes the output block to the frame and grid sizes
+(and refuses frames too large for VMEM).
 """
 
 from __future__ import annotations
@@ -10,79 +12,77 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels.warp.warp import autotune_block_rows  # noqa: F401 (re-export)
+from repro.kernels import interpret_mode
+from repro.kernels.warp.warp import autotune_block_rows
 from repro.kernels.warp.warp import coadd_clip as _coadd_clip
 from repro.kernels.warp.warp import coadd_fused as _coadd_fused
 from repro.kernels.warp.warp import coadd_hist as _coadd_hist
 from repro.kernels.warp.warp import coadd_moments as _coadd_moments
 from repro.kernels.warp.warp import mosaic_bricks as _mosaic_bricks
-from repro.kernels.warp.warp import warp_project as _warp_project
 
 
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def warp_project(image, wcs_vec, accept, grid_ra, grid_dec, block_rows=8, interpret=True):
-    return _warp_project(
-        image, wcs_vec, accept, grid_ra, grid_dec,
-        block_rows=block_rows, interpret=interpret,
+def _rows(block_rows, pixels, grid_ra, psf_kernels):
+    if block_rows is not None:
+        return block_rows
+    return autotune_block_rows(
+        grid_ra.shape[0], pixels.shape[1], pixels.shape[2],
+        psf_kernel_width=0 if psf_kernels is None else psf_kernels.shape[-1],
+        psf_kernel_2d=psf_kernels is not None and psf_kernels.ndim == 3,
     )
 
 
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def warp_batch(pixels, wcs_vecs, accepts, grid_ra, grid_dec, block_rows=8, interpret=True):
-    """(N,H,W) -> (N,Q,Q) tiles + coverages, vmapping the single-image kernel."""
-    fn = lambda p, w, a: _warp_project(  # noqa: E731
-        p, w, a, grid_ra, grid_dec, block_rows=block_rows, interpret=interpret
-    )
-    return jax.vmap(fn)(pixels, wcs_vecs, accepts)
-
-
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("block_rows",))
 def coadd_fused(pixels, wcs_vecs, accepts, grid_ra, grid_dec, psf_kernels=None,
-                block_rows=8, interpret=True):
+                block_rows=None):
     """Fused map+reduce: (N,H,W) images -> (Q,Q) coadd + depth.
 
-    ``psf_kernels`` (N, K), when given, PSF-matches each image inside the
-    kernel before warping (banded-matmul separable convolution).
+    ``psf_kernels`` (N, K) or (N, K, K), when given, PSF-matches each image
+    inside the kernel before warping (banded-matmul convolution).
     """
     return _coadd_fused(
         pixels, wcs_vecs, accepts, grid_ra, grid_dec, psf_kernels=psf_kernels,
-        block_rows=block_rows, interpret=interpret,
+        block_rows=_rows(block_rows, pixels, grid_ra, psf_kernels),
+        interpret=interpret_mode(),
     )
 
 
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("block_rows",))
 def coadd_moments(pixels, wcs_vecs, accepts, grid_ra, grid_dec,
-                  psf_kernels=None, block_rows=8, interpret=True):
+                  psf_kernels=None, block_rows=None):
     """Fused robust pass 1: (N,H,W) images -> (S0, S1, S2) moment maps."""
     return _coadd_moments(
         pixels, wcs_vecs, accepts, grid_ra, grid_dec, psf_kernels=psf_kernels,
-        block_rows=block_rows, interpret=interpret,
+        block_rows=_rows(block_rows, pixels, grid_ra, psf_kernels),
+        interpret=interpret_mode(),
     )
 
 
-@partial(jax.jit, static_argnames=("block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("block_rows",))
 def coadd_clip(pixels, wcs_vecs, accepts, grid_ra, grid_dec, center, thresh,
-               psf_kernels=None, block_rows=8, interpret=True):
+               psf_kernels=None, block_rows=None):
     """Fused robust final pass: accumulate samples inside the clip window."""
     return _coadd_clip(
         pixels, wcs_vecs, accepts, grid_ra, grid_dec, center, thresh,
-        psf_kernels=psf_kernels, block_rows=block_rows, interpret=interpret,
+        psf_kernels=psf_kernels,
+        block_rows=_rows(block_rows, pixels, grid_ra, psf_kernels),
+        interpret=interpret_mode(),
     )
 
 
-@partial(jax.jit, static_argnames=("nbins", "block_rows", "interpret"))
+@partial(jax.jit, static_argnames=("nbins", "block_rows"))
 def coadd_hist(pixels, wcs_vecs, accepts, grid_ra, grid_dec, lo, inv_w,
-               nbins=16, psf_kernels=None, block_rows=8, interpret=True):
+               nbins=16, psf_kernels=None, block_rows=None):
     """Fused median round 1: (nbins, Q, Q) weighted binapprox histogram."""
     return _coadd_hist(
         pixels, wcs_vecs, accepts, grid_ra, grid_dec, lo, inv_w, nbins=nbins,
-        psf_kernels=psf_kernels, block_rows=block_rows, interpret=interpret,
+        psf_kernels=psf_kernels,
+        block_rows=_rows(block_rows, pixels, grid_ra, psf_kernels),
+        interpret=interpret_mode(),
     )
 
 
-@partial(jax.jit, static_argnames=("npix", "interpret"))
-def mosaic_bricks(tiles, covs, offsets, npix, interpret=True):
+@partial(jax.jit, static_argnames=("npix",))
+def mosaic_bricks(tiles, covs, offsets, npix):
     """(B,bh,bw) cached brick tiles + weights -> (npix,npix) coadd + depth."""
-    return _mosaic_bricks(tiles, covs, offsets, npix, interpret=interpret)
+    return _mosaic_bricks(tiles, covs, offsets, npix, interpret=interpret_mode())

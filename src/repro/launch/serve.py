@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     CoaddEngine,
     CoaddQuery,
@@ -103,6 +104,7 @@ def main(argv=None):
                     help="assert the serving contract; exit 1 on violation")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     survey = make_survey(DRILL_SURVEY)
     engine = CoaddEngine(survey, pack_capacity=16)
     queries = drill_queries(args.seed, args.clients, args.pool)

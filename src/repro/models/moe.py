@@ -97,13 +97,11 @@ def moe_apply_shardmap(params, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.nd
             aux = jax.lax.pmean(aux, ax)
         return out, aux
 
-    from repro.distributed.sharding import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         in_specs=(P(), P(axes, None, None)),
         out_specs=(P(axes, None, None), P()),
-        check=False,
+        check_vma=False,
     )
     return fn(params, x)
 

@@ -402,12 +402,41 @@ def test_classification_taxonomy():
     assert classify(TransientFault("x")) == "transient"
     assert classify(ConnectionError("x")) == "transient"
     assert classify(OSError("x")) == "transient"
-    assert classify(RuntimeError("xla")) == "transient"  # XLA policy
+    assert classify(RuntimeError("xla")) == "transient"  # injected faults
     assert classify(PoisonedChunkError([3])) == "transient"
     assert classify(DeterminismError("x")) == "fatal"
     assert classify(QueryKilled("x")) == "fatal"
     assert classify(ValueError("x")) == "fatal"
     assert classify(KeyError("x")) == "fatal"
+
+
+def test_xla_compile_and_oom_errors_are_fatal():
+    """A program the compiler refuses, or one that exhausts device memory,
+    fails identically on every retry: no backoff loop, no quarantine."""
+    import jax
+    import jax.numpy as jnp
+
+    with pytest.raises(jax.errors.JaxRuntimeError) as oom:
+        jnp.zeros((1 << 40,), jnp.float32).block_until_ready()
+    assert str(oom.value).startswith("RESOURCE_EXHAUSTED")
+    assert classify(oom.value) == "fatal"
+    compile_err = jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: unsupported shape cast"
+    )
+    assert classify(compile_err) == "fatal"
+    assert classify(jax.errors.JaxRuntimeError("UNAVAILABLE: link down")) \
+        == "transient"
+
+    tr = WindowTracker(max_attempts=5)
+    attempts = [0]
+
+    def dispatch(ops, win, quarantined):
+        attempts[0] += 1
+        raise oom.value
+
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        tr.run([_FakeWin(0)], lambda w, q: "ops", dispatch, {})
+    assert attempts[0] == 1 and tr.counters.retries == 0
 
 
 def test_partial_digest_distinguishes_content():

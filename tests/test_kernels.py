@@ -25,10 +25,14 @@ def test_warp_kernel_matches_ref(npix, block_rows):
     wv = jnp.asarray(np.stack([SURVEY.images[i].wcs.to_vector() for i in ids]))
     acc = jnp.ones((len(ids),), jnp.float32)
     t_r, c_r = wref.warp_batch_ref(px, wv, acc, gr, gd)
-    t_k, c_k = wops.warp_batch(px, wv, acc, gr, gd, block_rows=block_rows)
     assert float(jnp.abs(t_r).max()) > 0  # non-trivial
-    np.testing.assert_allclose(np.asarray(t_k), np.asarray(t_r), atol=2e-2, rtol=1e-4)
-    np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_r))
+    # A one-image stack's coadd is that image's projected tile.
+    for i in range(len(ids)):
+        t_k, c_k = wops.coadd_fused(px[i:i + 1], wv[i:i + 1], acc[i:i + 1],
+                                    gr, gd, block_rows=block_rows)
+        np.testing.assert_allclose(np.asarray(t_k), np.asarray(t_r[i]),
+                                   atol=2e-2, rtol=1e-4)
+        np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_r[i]))
 
 
 @pytest.mark.parametrize("npix", [32, 64])
@@ -54,7 +58,7 @@ def test_warp_kernel_rejects_on_accept_gate():
     gr, gd = map(jnp.asarray, query_grid_sky(q))
     px = jnp.asarray(np.stack([SURVEY.images[i].pixels for i in ids]))
     wv = jnp.asarray(np.stack([SURVEY.images[i].wcs.to_vector() for i in ids]))
-    t, c = wops.warp_batch(px, wv, jnp.zeros((2,), jnp.float32), gr, gd)
+    t, c = wops.coadd_fused(px, wv, jnp.zeros((2,), jnp.float32), gr, gd)
     assert float(jnp.abs(t).max()) == 0 and float(jnp.abs(c).max()) == 0
 
 
@@ -115,3 +119,20 @@ def test_ssd_kernel_sweep(t, h, n, p, chunk):
     scale = float(jnp.abs(y_r).max())
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
                                atol=2e-4 * max(scale, 1.0))
+
+
+def test_empty_slot_projects_to_exact_zeros():
+    """A padding slot (all-zero WCS, det 0) maps to NaN source coordinates;
+    its samples must still be exact zeros, not NaN * 0 (eager ops here do
+    not get XLA's multiply-by-mask-to-select rewrite, and the TPU doesn't
+    either)."""
+    from repro.core.mapper import bilinear_sample, project_one
+
+    nan = jnp.full((4, 4), jnp.nan, jnp.float32)
+    val, m = bilinear_sample(jnp.ones((8, 8), jnp.float32), nan, nan)
+    assert np.all(np.asarray(val) == 0) and np.all(np.asarray(m) == 0)
+    gr = jnp.full((4, 4), 37.6, jnp.float32)
+    tile, cov = project_one(jnp.ones((8, 8), jnp.float32),
+                            jnp.zeros((8,), jnp.float32), jnp.float32(0.0),
+                            gr, jnp.zeros((4, 4), jnp.float32))
+    assert np.all(np.asarray(tile) == 0) and np.all(np.asarray(cov) == 0)

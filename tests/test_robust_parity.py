@@ -164,8 +164,7 @@ def test_bricks_match_golden(engine, golden, red):
 
 @pytest.mark.parametrize("red", ROBUST)
 def test_pallas_matches_xla(survey, engine, red):
-    kern = CoaddEngine(survey, pack_capacity=8, use_kernel=True,
-                       kernel_interpret=True)
+    kern = CoaddEngine(survey, pack_capacity=8, use_kernel=True)
     a = engine.run(QUERY, "sql_structured", reduce=red)
     b = kern.run(QUERY, "sql_structured", reduce=red)
     np.testing.assert_array_equal(a.depth, b.depth)
