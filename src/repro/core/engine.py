@@ -119,6 +119,7 @@ from repro.core.seqfile import (
     pack_structured,
     pack_unstructured,
 )
+from repro.core.spans import span
 from repro.core.survey import Survey
 from repro.distributed.sharding import (
     shard_count,
@@ -214,6 +215,15 @@ class JobStats:
     # every eager path — the fused program re-scans internally).
     reduce: str = "mean"
     reduce_passes: int = 1
+    # Transfer accounting, from array metadata (no sync): bytes of the
+    # per-query operands this call copied to the device (output grid, slot
+    # gate, pack index, query vector; a layout's one-time upload is not
+    # counted) and bytes it read back (scalars, coadd, depth).  The same
+    # numbers are the ``h2d_bytes``/``d2h_bytes`` arguments of the
+    # ``coadd.execute.*`` spans.  Counted on the eager resident `execute`;
+    # zero on the streaming, batch, brick and distributed paths.
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
 
 
 @dataclasses.dataclass
@@ -230,6 +240,11 @@ class CoaddResult:
         return np.where(
             self.depth > 0, self.coadd / np.where(self.depth > 0, self.depth, 1.0), 0.0
         )
+
+
+def _nbytes(*arrays) -> int:
+    """Summed size of the arrays (None counts 0), from metadata: no sync."""
+    return sum(int(a.nbytes) for a in arrays if a is not None)
 
 
 def _query_vec(query: CoaddQuery) -> np.ndarray:
@@ -1302,67 +1317,65 @@ class CoaddEngine:
     # ----- planning: the six methods differ ONLY in gate construction -----
     def plan(self, query: CoaddQuery, method: str,
              reduce: str = "mean") -> CoaddPlan:
-        if method not in METHODS:
-            raise ValueError(f"unknown method {method}; expected one of {METHODS}")
-        if reduce not in reducer.REDUCERS:
-            raise ValueError(
-                f"unknown reduce {reduce!r}; expected one of {reducer.REDUCERS}"
-            )
-        plan = getattr(self, f"plan_{method}")(query)
-        # The reduction variant is plan state (it changes the result bytes):
-        # set after the method planner so all six stay reduce-agnostic.
-        plan.reduce = reduce
-        return plan
+        with span("plan"):
+            if method not in METHODS:
+                raise ValueError(
+                    f"unknown method {method}; expected one of {METHODS}")
+            if reduce not in reducer.REDUCERS:
+                raise ValueError(
+                    f"unknown reduce {reduce!r}; expected one of {reducer.REDUCERS}"
+                )
+            plan = getattr(self, f"plan_{method}")(query)
+            # The reduction variant is plan state (it changes the result
+            # bytes): set after the method planner so all six stay
+            # reduce-agnostic.
+            plan.reduce = reduce
+            return plan
 
     def plan_raw_fits(self, query: CoaddQuery) -> CoaddPlan:
         ds = self.dataset("per_file")
-        t0 = time.perf_counter()
-        # No prefilter: every file is "located" and becomes a mapper input.
-        gate = ds.valid.copy()
-        t_locate = time.perf_counter() - t0
+        with span("plan.locate") as locate:
+            # No prefilter: every file is "located" and becomes a mapper input.
+            gate = ds.valid.copy()
         return CoaddPlan("raw_fits", "per_file", gate, _query_vec(query),
-                         query, t_locate, psf_target=self.match_psf_sigma)
+                         query, locate.seconds, psf_target=self.match_psf_sigma)
 
     def plan_raw_fits_prefiltered(self, query: CoaddQuery) -> CoaddPlan:
         ds = self.dataset("per_file")
-        t0 = time.perf_counter()
-        mask = glob_file_mask(self.survey.meta_table(), query, self.camcol_dec)
-        gate = ds.valid & mask[:, None]  # per-file layout: pack == file
-        t_locate = time.perf_counter() - t0
+        with span("plan.locate") as locate:
+            mask = glob_file_mask(self.survey.meta_table(), query, self.camcol_dec)
+            gate = ds.valid & mask[:, None]  # per-file layout: pack == file
         return CoaddPlan("raw_fits_prefiltered", "per_file", gate,
-                         _query_vec(query), query, t_locate,
+                         _query_vec(query), query, locate.seconds,
                          psf_target=self.match_psf_sigma)
 
     def plan_unstructured_seq(self, query: CoaddQuery) -> CoaddPlan:
         ds = self.dataset("unstructured")
-        t0 = time.perf_counter()
-        gate = ds.valid.copy()  # unprunable by construction: read every pack
-        t_locate = time.perf_counter() - t0
+        with span("plan.locate") as locate:
+            gate = ds.valid.copy()  # unprunable by construction: read every pack
         return CoaddPlan("unstructured_seq", "unstructured", gate,
-                         _query_vec(query), query, t_locate,
+                         _query_vec(query), query, locate.seconds,
                          psf_target=self.match_psf_sigma)
 
     def plan_structured_seq_prefiltered(self, query: CoaddQuery) -> CoaddPlan:
         ds = self.dataset("structured")
-        t0 = time.perf_counter()
-        mask = glob_pack_mask(ds, query, self.camcol_dec)
-        gate = ds.valid & mask[:, None]
-        t_locate = time.perf_counter() - t0
+        with span("plan.locate") as locate:
+            mask = glob_pack_mask(ds, query, self.camcol_dec)
+            gate = ds.valid & mask[:, None]
         return CoaddPlan("structured_seq_prefiltered", "structured", gate,
-                         _query_vec(query), query, t_locate,
+                         _query_vec(query), query, locate.seconds,
                          psf_target=self.match_psf_sigma)
 
     def _plan_sql(self, layout: str, query: CoaddQuery, method: str) -> CoaddPlan:
         ds = self.dataset(layout)
-        t0 = time.perf_counter()
-        ids = self.sql.select(query)
-        # The index maps ids -> (pack, slot); the "gather" is a metadata-only
-        # slot gate over the resident containers, so exact selection costs no
-        # pixel movement at all.
-        gate = ds.slot_mask(ids)
-        t_locate = time.perf_counter() - t0
+        with span("plan.locate") as locate:
+            ids = self.sql.select(query)
+            # The index maps ids -> (pack, slot); the "gather" is a
+            # metadata-only slot gate over the resident containers, so exact
+            # selection costs no pixel movement at all.
+            gate = ds.slot_mask(ids)
         return CoaddPlan(method, layout, gate, _query_vec(query), query,
-                         t_locate, psf_target=self.match_psf_sigma)
+                         locate.seconds, psf_target=self.match_psf_sigma)
 
     def plan_sql_unstructured(self, query: CoaddQuery) -> CoaddPlan:
         return self._plan_sql("unstructured", query, "sql_unstructured")
@@ -1840,57 +1853,70 @@ class CoaddEngine:
         `packs_gated` instead of the layout size, still in one dispatch.
         Under a device budget the query streams instead
         (`_execute_streaming`): windowed scans over budget-sized chunks.
+
+        The eager path is traced (`repro.core.spans`): ``coadd.execute``
+        holds ``prepare`` (``grid``, ``compact``, ``dispatch``), ``sync``
+        (the one host sync) and ``fetch`` (the copy back); its transfers
+        are `JobStats.h2d_bytes` / ``d2h_bytes``.
         """
         self._check_plan_psf(plan)
         if self.device_budget_bytes is not None:
             if plan.reduce != "mean":
                 return self._execute_streaming_robust(plan)
             return self._execute_streaming(plan)
-        exec_ds, _ = self.exec_dataset(plan.layout)
-        gate = self._exec_gate(plan)
-        m_builds0 = self.matched_builds
-        fn, args, kwargs, sp, m_hits = self._eager_program(plan)
-        t1 = time.perf_counter()
-        self.dispatch_count += 1
-        coadd, depth, contrib, considered = fn(*args, **kwargs)
-        coadd.block_until_ready()
-        t2 = time.perf_counter()
-        scanned = sp.budget if sp is not None else exec_ds.n_packs
-        stats = JobStats(
-            method=plan.method,
-            files_considered=int(considered),
-            files_contributing=int(contrib),
-            packs_touched=plan.packs_touched,
-            t_locate_s=plan.t_locate_s,
-            t_map_reduce_s=t2 - t1,
-            t_total_s=plan.t_locate_s + (t2 - t1),
-            dispatches=1,
-            packs_gated=int(gate.any(axis=1).sum()),
-            packs_scanned=scanned,
-            scan_budget=scanned,
-            matched_cache_builds=self.matched_builds - m_builds0,
-            matched_cache_hits=m_hits,
-            peak_resident_bytes=self._peak_resident_bytes(),
-            reduce=plan.reduce,
-        )
-        return CoaddResult(np.asarray(coadd), np.asarray(depth), stats)
+        with span("execute"):
+            with span("execute.prepare"):
+                exec_ds, _ = self.exec_dataset(plan.layout)
+                gate = self._exec_gate(plan)
+                m_builds0 = self.matched_builds
+                fn, args, kwargs, sp, m_hits, h2d = self._eager_program(plan)
+                with span("execute.dispatch") as dispatch:
+                    self.dispatch_count += 1
+                    coadd, depth, contrib, considered = fn(*args, **kwargs)
+            with span("execute.sync") as sync:
+                coadd.block_until_ready()
+            with span("execute.fetch") as fetch:
+                d2h = _nbytes(coadd, depth, contrib, considered)
+                fetch.set(d2h_bytes=d2h)
+                t_mr = dispatch.seconds + sync.seconds
+                scanned = sp.budget if sp is not None else exec_ds.n_packs
+                stats = JobStats(
+                    method=plan.method,
+                    files_considered=int(considered),
+                    files_contributing=int(contrib),
+                    packs_touched=plan.packs_touched,
+                    t_locate_s=plan.t_locate_s,
+                    t_map_reduce_s=t_mr,
+                    t_total_s=plan.t_locate_s + t_mr,
+                    dispatches=1,
+                    packs_gated=int(gate.any(axis=1).sum()),
+                    packs_scanned=scanned,
+                    scan_budget=scanned,
+                    matched_cache_builds=self.matched_builds - m_builds0,
+                    matched_cache_hits=m_hits,
+                    peak_resident_bytes=self._peak_resident_bytes(),
+                    reduce=plan.reduce,
+                    h2d_bytes=h2d,
+                    d2h_bytes=d2h,
+                )
+                return CoaddResult(np.asarray(coadd), np.asarray(depth), stats)
 
     def _eager_program(self, plan: CoaddPlan):
         """The jitted program and operands `execute` dispatches for a plan
         against the eager resident layout.
 
-        Returns ``(fn, args, kwargs, sparse_index, matched_cache_hits)``;
-        the call returns ``(coadd, depth, contributing, considered)``.
-        Uploads the layout (and builds the matched-pixel cache) on first
-        use, exactly as the dispatch itself would need.
+        Returns ``(fn, args, kwargs, sparse_index, matched_cache_hits,
+        h2d_bytes)``; the call returns ``(coadd, depth, contributing,
+        considered)``.  Uploads the layout (and builds the matched-pixel
+        cache) on first use, exactly as the dispatch itself would need.
+        The per-query operands are built under the ``coadd.execute.grid``
+        and ``coadd.execute.compact`` spans; ``h2d_bytes`` is their size.
         """
         ds = self.dataset(plan.layout)
         # Before the upload: a kernel-lane query whose frames cannot fit
         # VMEM is refused here, not after moving the archive to the device.
         block_rows = self._block_rows(plan.query, ds)
         dev = self.device_dataset(plan.layout)
-        gate = self._exec_gate(plan)
-        grid_ra, grid_dec = self._plan_grids(plan)
         psf_kernels = self._device_psf_kernels(plan.layout)
         m_hits = 0
         if self._matched_mode():
@@ -1898,31 +1924,37 @@ class CoaddEngine:
             # operand, no per-query convolution.
             dev, m_hits = self._matched_device_dataset(plan.layout, dev)
             psf_kernels = None
-        sp = self._sparse_index(gate)
+        with span("execute.grid") as grid:
+            grid_ra, grid_dec = self._plan_grids(plan)
+            h2d_grid = _nbytes(grid_ra, grid_dec)
+            grid.set(h2d_bytes=h2d_grid)
+        robust = plan.reduce != "mean"
+        with span("execute.compact") as compact:
+            gate = self._exec_gate(plan)
+            sp = self._sparse_index(gate)
+            gate_dev = jnp.asarray(compact_gate(gate, sp) if sp is not None
+                                   else gate)
+            pack_idx = jnp.asarray(sp.pack_idx) if sp is not None else None
+            qvec = jnp.asarray(plan.qvec)
+            clip_k = jnp.float32(self.clip_k) if robust else None
+            h2d_compact = _nbytes(gate_dev, pack_idx, qvec, clip_k)
+            compact.set(h2d_bytes=h2d_compact)
+        h2d = h2d_grid + h2d_compact
         operands = (dev.pixels, dev.wcs, dev.ints, dev.floats, psf_kernels)
         kwargs = dict(use_kernel=self.use_kernel, block_rows=block_rows)
-        if plan.reduce != "mean":
+        if robust:
             # Robust eager path: all passes fused into ONE jitted dispatch
             # (the in-program re-scan is what keeps clipped within the
             # perf-gate overhead budget vs the mean).
-            gate_dev = (jnp.asarray(compact_gate(gate, sp)) if sp is not None
-                        else jnp.asarray(gate))
-            kwargs.update(
-                reduce=plan.reduce,
-                median_bins=self.median_bins,
-                pack_idx=jnp.asarray(sp.pack_idx) if sp is not None else None,
-            )
-            args = operands + (gate_dev, jnp.asarray(plan.qvec), grid_ra,
-                               grid_dec, jnp.float32(self.clip_k))
-            return _robust_scan, args, kwargs, sp, m_hits
+            kwargs.update(reduce=plan.reduce, median_bins=self.median_bins,
+                          pack_idx=pack_idx)
+            args = operands + (gate_dev, qvec, grid_ra, grid_dec, clip_k)
+            return _robust_scan, args, kwargs, sp, m_hits, h2d
         if sp is not None:
-            args = operands + (jnp.asarray(sp.pack_idx),
-                               jnp.asarray(compact_gate(gate, sp)),
-                               jnp.asarray(plan.qvec), grid_ra, grid_dec)
-            return _coadd_scan_sparse, args, kwargs, sp, m_hits
-        args = operands + (jnp.asarray(gate), jnp.asarray(plan.qvec),
-                           grid_ra, grid_dec)
-        return _coadd_scan, args, kwargs, sp, m_hits
+            args = operands + (pack_idx, gate_dev, qvec, grid_ra, grid_dec)
+            return _coadd_scan_sparse, args, kwargs, sp, m_hits, h2d
+        args = operands + (gate_dev, qvec, grid_ra, grid_dec)
+        return _coadd_scan, args, kwargs, sp, m_hits, h2d
 
     def lower(self, plan: CoaddPlan) -> "jax.stages.Lowered":
         """The program `execute(plan)` dispatches, lowered but not run.
@@ -1936,7 +1968,7 @@ class CoaddEngine:
             raise ValueError("lower() covers the eager path; this engine "
                              "streams under a device budget")
         self._check_plan_psf(plan)
-        fn, args, kwargs, _, _ = self._eager_program(plan)
+        fn, args, kwargs, *_ = self._eager_program(plan)
         return fn.lower(*args, **kwargs)
 
     def _eager_resident_bytes(self) -> int:
