@@ -126,6 +126,7 @@ from repro.distributed.sharding import (
     shard_local_compaction,
 )
 from repro.kernels.warp import ops as warp_ops
+from repro.kernels.warp import windowed
 
 METHODS = (
     "raw_fits",
@@ -224,6 +225,15 @@ class JobStats:
     # zero on the streaming, batch, brick and distributed paths.
     h2d_bytes: int = 0
     d2h_bytes: int = 0
+    # Pack-axis scan steps that warped through the windowed kernel
+    # (`kernels.warp.windowed`), as the device counts them; the rest of
+    # ``packs_scanned`` took the XLA gather.  Counted on the eager resident,
+    # streaming and batch mean paths (the ones that can take it).  The
+    # ``coadd.execute.dispatch`` span's ``windowed_packs`` argument is the
+    # pack steps handed to the windowed program; the two differ only when
+    # the kernel's guard voided the answer and the query was redone
+    # through the gather (`_covered`), which counts 0 here.
+    windowed_packs: int = 0
 
 
 @dataclasses.dataclass
@@ -264,6 +274,15 @@ def _query_vec(query: CoaddQuery) -> np.ndarray:
         ],
         np.float32,
     )
+
+
+def _covered(steps) -> bool:
+    """Whether a mean scan's answer stands: no pack step's guard found a
+    tap outside its windows (`kernels.warp.windowed`).  ``steps`` is the
+    scan's fifth result, [pack steps warped, pack steps voided] (per query
+    in a batch; zeros where the gather ran).  A voided answer is redone
+    through the gather."""
+    return not np.asarray(steps)[..., 1].any()
 
 
 def _accept_from_meta(ints, floats, qvec):
@@ -317,6 +336,7 @@ def _scan_coadd(
     use_kernel,
     block_rows,
     pack_idx=None,  # (G,) int32 — sparse: scan only these packs of the layout
+    window=None,    # WindowFit: warp each pack with `coadd_windowed`
 ):
     """The whole query in ONE XLA program: scan packs, fuse map+reduce.
 
@@ -334,29 +354,45 @@ def _scan_coadd(
     with a scalar index) — the gather rides inside the scan, so no
     (G, cap, H, W) compacted copy ever materializes next to the resident
     layout.  ``gate`` must then be the (G, cap) compacted gate.
+
+    Windowed mode (``window`` given, `CoaddEngine._window_fit`): each step
+    hands the pack's index to `coadd_windowed`, which reads only each
+    output tile's source window out of the resident pixels, in place of the
+    XLA lane's four whole-pack gathers.  The fifth result is the int32
+    pair [steps whose windows held every tap, steps whose guard found one
+    outside] (zeros without ``window``): any of the second voids the
+    answer, and the engine redoes the query through the gather
+    (`_covered`).
     """
 
     def body(carry, px, wv, ints_p, floats_p, kern_p, gate_p):
-        coadd, depth, contrib = carry
+        coadd, depth, contrib, steps = carry
         accept = _accept_from_meta(ints_p, floats_p, qvec) & gate_p
-        c, d = _map_reduce(px, wv, accept, grid_ra, grid_dec, kern_p,
-                           use_kernel, block_rows)
-        return (coadd + c, depth + d, contrib + accept.sum()), None
+        if window is not None:
+            c, d, covered = warp_ops.coadd_windowed(
+                pixels, px, wv, accept, grid_ra, grid_dec, fit=window)
+            steps = steps + jnp.stack([covered, 1 - covered])
+        else:
+            c, d = _map_reduce(px, wv, accept, grid_ra, grid_dec, kern_p,
+                               use_kernel, block_rows)
+        return (coadd + c, depth + d, contrib + accept.sum(), steps), None
 
     q = grid_ra.shape[0]
     init = (
         jnp.zeros((q, q), jnp.float32),
         jnp.zeros((q, q), jnp.float32),
         jnp.zeros((), jnp.int32),
+        jnp.zeros((2,), jnp.int32),
     )
-    (coadd, depth, contrib), _ = _scan_packs(
-        body, init, pixels, wcs, ints, floats, psf_kernels, gate, pack_idx
+    (coadd, depth, contrib, steps), _ = _scan_packs(
+        body, init, pixels, wcs, ints, floats, psf_kernels, gate, pack_idx,
+        resident=window is not None,
     )
-    return coadd, depth, contrib, gate.sum()
+    return coadd, depth, contrib, gate.sum(), steps
 
 
 def _scan_packs(body, init, pixels, wcs, ints, floats, psf_kernels, gate,
-                pack_idx):
+                pack_idx, resident=False):
     """Shared pack-scan plumbing: dense xs, or sparse streamed gather.
 
     ``body(carry, px, wv, ints_p, floats_p, kern_p, gate_p)`` is the per-pack
@@ -368,63 +404,79 @@ def _scan_packs(body, init, pixels, wcs, ints, floats, psf_kernels, gate,
     Returns ``(carry, ys)``: bodies that emit per-pack outputs (the resident
     warp cache in `_robust_passes`) get them stacked along a leading pack
     axis; monoid-only bodies return None ys.
+
+    ``resident=True`` hands the body the pack's index in place of its
+    pixels, for bodies that read the resident array themselves.
     """
     if pack_idx is None:
         def step(carry, xs):
             px, wv, ints_p, floats_p, kern_p, gate_p = xs
             return body(carry, px, wv, ints_p, floats_p, kern_p, gate_p)
 
-        xs = (pixels, wcs, ints, floats, psf_kernels, gate)
+        first = jnp.arange(pixels.shape[0], dtype=jnp.int32) if resident else pixels
+        xs = (first, wcs, ints, floats, psf_kernels, gate)
     else:
         def step(carry, xs):
             i, gate_p = xs
             px, wv, ints_p, floats_p, kern_p = mapper.gather_packs(
                 i, pixels, wcs, ints, floats, psf_kernels
             )
-            return body(carry, px, wv, ints_p, floats_p, kern_p, gate_p)
+            return body(carry, i if resident else px, wv, ints_p, floats_p,
+                        kern_p, gate_p)
 
         xs = (pack_idx, gate)
 
     return jax.lax.scan(step, init, xs)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "window"))
 def _coadd_scan(
     pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-    use_kernel=False, block_rows=None,
+    use_kernel=False, block_rows=None, window=None,
 ):
     """One plan against a device-resident layout, as one jitted program."""
     return _scan_coadd(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        use_kernel, block_rows,
+        use_kernel, block_rows, window=window,
     )
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
+def _over_queries(one, window, gates, qvecs, grids_ra, grids_dec):
+    """Map a per-query scan over the stacked query axis: vmapped, or, in
+    windowed mode, one query after another (`lax.map`), so each query's
+    scan is the very program a solo query runs (the kernel's hand-written
+    DMAs have no batching rule) and its answer does not depend on whether
+    the service coalesced it."""
+    if window is None:
+        return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec)
+    return jax.lax.map(lambda xs: one(*xs), (gates, qvecs, grids_ra, grids_dec))
+
+
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "window"))
 def _coadd_scan_batch(
     pixels, wcs, ints, floats, psf_kernels, gates, qvecs, grids_ra, grids_dec,
-    use_kernel=False, block_rows=None,
+    use_kernel=False, block_rows=None, window=None,
 ):
     """K stacked plans against one resident layout, as ONE jitted program.
 
     vmaps the scan's gate/qvec/grid axes over the query dimension while the
     resident pack arrays broadcast — the batched multi-query job of paper
-    Fig. 5 with zero extra pixel traffic.
+    Fig. 5 with zero extra pixel traffic (`_over_queries`).
     """
 
     def one(gate, qvec, grid_ra, grid_dec):
         return _scan_coadd(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
-            grid_dec, use_kernel, block_rows,
+            grid_dec, use_kernel, block_rows, window=window,
         )
 
-    return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec)
+    return _over_queries(one, window, gates, qvecs, grids_ra, grids_dec)
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "window"))
 def _coadd_scan_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gate, qvec, grid_ra,
-    grid_dec, use_kernel=False, block_rows=None,
+    grid_dec, use_kernel=False, block_rows=None, window=None,
 ):
     """Sparse plan against a resident layout, still ONE jitted program.
 
@@ -436,14 +488,14 @@ def _coadd_scan_sparse(
     """
     return _scan_coadd(
         pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra, grid_dec,
-        use_kernel, block_rows, pack_idx=pack_idx,
+        use_kernel, block_rows, pack_idx=pack_idx, window=window,
     )
 
 
-@partial(jax.jit, static_argnames=("use_kernel", "block_rows"))
+@partial(jax.jit, static_argnames=("use_kernel", "block_rows", "window"))
 def _coadd_scan_batch_sparse(
     pixels, wcs, ints, floats, psf_kernels, pack_idx, gates, qvecs, grids_ra,
-    grids_dec, use_kernel=False, block_rows=None,
+    grids_dec, use_kernel=False, block_rows=None, window=None,
 ):
     """K stacked plans over the union of their gated packs, ONE program.
 
@@ -458,9 +510,10 @@ def _coadd_scan_batch_sparse(
         return _scan_coadd(
             pixels, wcs, ints, floats, psf_kernels, gate, qvec, grid_ra,
             grid_dec, use_kernel, block_rows, pack_idx=pack_idx,
+            window=window,
         )
 
-    return jax.vmap(one)(gates, qvecs, grids_ra, grids_dec)
+    return _over_queries(one, window, gates, qvecs, grids_ra, grids_dec)
 
 
 # ----- robust reductions: monoidal pass programs (DESIGN.md §11) -----------
@@ -1314,6 +1367,39 @@ class CoaddEngine:
             psf_kernel_2d=bank is not None and bank.ndim == 4,
         )
 
+    def _batch_window_fit(self, plans, exec_ds: PackedDataset, gates,
+                          psf_in_dispatch: bool,
+                          pixels) -> Optional[windowed.WindowFit]:
+        """One windowed shape for a batch (`windowed.common_fit`): each
+        query's scan is then the program its solo `execute` runs.  A batch
+        whose queries do not all fit alike keeps the gather for all."""
+        return windowed.common_fit([
+            self._window_fit(p, exec_ds, g, psf_in_dispatch, pixels)
+            for p, g in zip(plans, gates)])
+
+    def _window_fit(self, plan: CoaddPlan, exec_ds: PackedDataset,
+                    gate: np.ndarray, psf_in_dispatch: bool,
+                    pixels) -> Optional[windowed.WindowFit]:
+        """The windowed warp's static shape for a mean scan, or None.
+
+        Chosen by what the plan and the data show, not by a knob: resident
+        ``pixels`` in a layout the kernel reads (`windowed.lane_axis`: on a
+        TPU), the XLA lane of a mean stack with no in-dispatch PSF
+        convolution, the query's own TAN grid, and every gated frame's
+        footprint over an output tile fitting a source window
+        (`windowed.window_fit`: at native scale and a small rotation it
+        does).  Anything else keeps the XLA gather.
+        """
+        lanes = windowed.lane_axis(pixels)
+        if (lanes is None or self.use_kernel or psf_in_dispatch
+                or plan.reduce != "mean" or plan.grid_sky is not None):
+            return None
+        h, w = exec_ds.image_hw()
+        return windowed.window_fit(
+            plan.query.grid_wcs_vector(), exec_ds.wcs[gate],
+            plan.query.npix, h, w, exec_ds.capacity, lanes,
+        )
+
     # ----- planning: the six methods differ ONLY in gate construction -----
     def plan(self, query: CoaddQuery, method: str,
              reduce: str = "mean") -> CoaddPlan:
@@ -1619,7 +1705,8 @@ class CoaddEngine:
                     self.residency.evictions - ev0)
         return acc, counters, elapsed, fc, quarantined
 
-    def _execute_streaming(self, plan: CoaddPlan) -> CoaddResult:
+    def _execute_streaming(self, plan: CoaddPlan,
+                           gather_only: bool = False) -> CoaddResult:
         """Windowed query under a device budget (DESIGN.md §6).
 
         The gated pack set is partitioned into residency-chunk windows;
@@ -1642,6 +1729,8 @@ class CoaddEngine:
         windows = self._stream_windows(exec_ds, gate.any(axis=1))
         qvec = jnp.asarray(plan.qvec)
         m_builds0, d0 = self.matched_builds, self.dispatch_count
+        psf_in_dispatch = (self.psf_kernel_bank(plan.layout) is not None
+                           and not self._matched_mode())
 
         def dispatch(dev, kern, win, dropped):
             g = gate
@@ -1665,14 +1754,20 @@ class CoaddEngine:
                 grid_dec,
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
+                window=None if gather_only else self._window_fit(
+                    plan, exec_ds, gate, psf_in_dispatch, dev.pixels),
             )
 
         job_key = self._job_key(plan.method, plan.layout, gate, plan.qvec,
                                 plan.query.npix, windows,
-                                grid_tag=self._grid_tag(plan))
-        (coadd, depth, contrib, considered), counters, elapsed, fc, quar = \
-            self._run_stream_windows(plan.layout, exec_ds, windows, dispatch,
-                                     job_key)
+                                grid_tag=self._grid_tag(plan)
+                                + ("|gather" if gather_only else ""))
+        (coadd, depth, contrib, considered, steps), counters, elapsed, fc, \
+            quar = self._run_stream_windows(plan.layout, exec_ds, windows,
+                                            dispatch, job_key)
+        steps = np.asarray(steps)
+        if not _covered(steps):
+            return self._execute_streaming(plan, gather_only=True)
         uploads, hits, evictions = counters
         # Coverage honesty: only quarantined packs this query's gate actually
         # opens are *uncovered* for it — persistent quarantine on packs the
@@ -1690,6 +1785,7 @@ class CoaddEngine:
             packs_gated=int(gate.any(axis=1).sum()),
             packs_scanned=sum(w.budget for w in windows),
             scan_budget=max(w.budget for w in windows),
+            windowed_packs=int(steps[0]),
             windows=len(windows),
             chunk_uploads=uploads,
             residency_hits=hits,
@@ -1870,16 +1966,30 @@ class CoaddEngine:
                 gate = self._exec_gate(plan)
                 m_builds0 = self.matched_builds
                 fn, args, kwargs, sp, m_hits, h2d = self._eager_program(plan)
+                scanned = sp.budget if sp is not None else exec_ds.n_packs
+                window = kwargs.get("window")
                 with span("execute.dispatch") as dispatch:
+                    dispatch.set(windowed_packs=scanned if window else 0)
                     self.dispatch_count += 1
-                    coadd, depth, contrib, considered = fn(*args, **kwargs)
+                    # Mean scans return a fifth result, the windowed pack
+                    # steps (`_covered`); it is read only in windowed mode.
+                    coadd, depth, contrib, considered, *steps = fn(*args,
+                                                                   **kwargs)
             with span("execute.sync") as sync:
                 coadd.block_until_ready()
+                steps = steps if window else []
+                counts = np.asarray(steps[0]) if steps else np.zeros(2)
+                redo = not _covered(counts)
+                if redo:
+                    self.dispatch_count += 1
+                    kwargs["window"] = None
+                    coadd, depth, contrib, considered, _ = fn(*args, **kwargs)
+                    coadd.block_until_ready()
+                    counts = np.zeros(2)
             with span("execute.fetch") as fetch:
-                d2h = _nbytes(coadd, depth, contrib, considered)
+                d2h = _nbytes(coadd, depth, contrib, considered, *steps)
                 fetch.set(d2h_bytes=d2h)
                 t_mr = dispatch.seconds + sync.seconds
-                scanned = sp.budget if sp is not None else exec_ds.n_packs
                 stats = JobStats(
                     method=plan.method,
                     files_considered=int(considered),
@@ -1888,7 +1998,7 @@ class CoaddEngine:
                     t_locate_s=plan.t_locate_s,
                     t_map_reduce_s=t_mr,
                     t_total_s=plan.t_locate_s + t_mr,
-                    dispatches=1,
+                    dispatches=1 + redo,
                     packs_gated=int(gate.any(axis=1).sum()),
                     packs_scanned=scanned,
                     scan_budget=scanned,
@@ -1898,6 +2008,7 @@ class CoaddEngine:
                     reduce=plan.reduce,
                     h2d_bytes=h2d,
                     d2h_bytes=d2h,
+                    windowed_packs=int(counts[0]),
                 )
                 return CoaddResult(np.asarray(coadd), np.asarray(depth), stats)
 
@@ -1907,7 +2018,8 @@ class CoaddEngine:
 
         Returns ``(fn, args, kwargs, sparse_index, matched_cache_hits,
         h2d_bytes)``; the call returns ``(coadd, depth, contributing,
-        considered)``.  Uploads the layout (and builds the matched-pixel
+        considered)``, and a mean scan also its windowed pack steps
+        (`_covered`).  Uploads the layout (and builds the matched-pixel
         cache) on first use, exactly as the dispatch itself would need.
         The per-query operands are built under the ``coadd.execute.grid``
         and ``coadd.execute.compact`` spans; ``h2d_bytes`` is their size.
@@ -1950,6 +2062,9 @@ class CoaddEngine:
                           pack_idx=pack_idx)
             args = operands + (gate_dev, qvec, grid_ra, grid_dec, clip_k)
             return _robust_scan, args, kwargs, sp, m_hits, h2d
+        kwargs["window"] = self._window_fit(
+            plan, self.exec_dataset(plan.layout)[0], gate,
+            psf_kernels is not None, dev.pixels)
         if sp is not None:
             args = operands + (pack_idx, gate_dev, qvec, grid_ra, grid_dec)
             return _coadd_scan_sparse, args, kwargs, sp, m_hits, h2d
@@ -2353,6 +2468,8 @@ class CoaddEngine:
         sp = self._sparse_index(gates)
         t1 = time.perf_counter()
         self.dispatch_count += 1
+        counts = np.zeros((len(plans), 2), np.int64)
+        n_dispatches = 1
         if plans[0].reduce != "mean":
             # Robust batch, still ONE dispatch: the fused per-query passes
             # vmap over the stacked gates/grids (stack_plans guarantees one
@@ -2377,35 +2494,28 @@ class CoaddEngine:
                 median_bins=self.median_bins,
                 pack_idx=pack_idx,
             )
-        elif sp is not None:
-            coadds, depths, contribs, considered = _coadd_scan_batch_sparse(
-                dev.pixels,
-                dev.wcs,
-                dev.ints,
-                dev.floats,
-                psf_kernels,
-                jnp.asarray(sp.pack_idx),
-                jnp.asarray(compact_gates(gates, sp)),
-                jnp.asarray(qvecs),
-                grids_ra,
-                grids_dec,
-                use_kernel=self.use_kernel,
-                block_rows=block_rows,
-            )
         else:
-            coadds, depths, contribs, considered = _coadd_scan_batch(
-                dev.pixels,
-                dev.wcs,
-                dev.ints,
-                dev.floats,
-                psf_kernels,
-                jnp.asarray(gates),
-                jnp.asarray(qvecs),
-                grids_ra,
-                grids_dec,
-                use_kernel=self.use_kernel,
-                block_rows=block_rows,
-            )
+            window = self._batch_window_fit(plans, exec_ds, gates,
+                                            psf_kernels is not None,
+                                            dev.pixels)
+            if sp is not None:
+                fn = partial(_coadd_scan_batch_sparse,
+                             pack_idx=jnp.asarray(sp.pack_idx),
+                             gates=jnp.asarray(compact_gates(gates, sp)))
+            else:
+                fn = partial(_coadd_scan_batch, gates=jnp.asarray(gates))
+            fn = partial(fn, dev.pixels, dev.wcs, dev.ints, dev.floats,
+                         psf_kernels, qvecs=jnp.asarray(qvecs),
+                         grids_ra=grids_ra, grids_dec=grids_dec,
+                         use_kernel=self.use_kernel, block_rows=block_rows)
+            coadds, depths, contribs, considered, steps = fn(window=window)
+            if window is not None:
+                counts = np.asarray(steps)
+                if not _covered(counts):
+                    n_dispatches += 1
+                    self.dispatch_count += 1
+                    coadds, depths, contribs, considered, _ = fn(window=None)
+                    counts = np.zeros_like(counts)
         coadds.block_until_ready()
         t2 = time.perf_counter()
         contribs = np.asarray(contribs)
@@ -2425,7 +2535,7 @@ class CoaddEngine:
                 t_locate_s=p.t_locate_s,
                 t_map_reduce_s=t_mr,
                 t_total_s=p.t_locate_s + t_mr,
-                dispatches=1 if i == 0 else 0,
+                dispatches=n_dispatches if i == 0 else 0,
                 packs_gated=int(gates[i].any(axis=1).sum()),
                 packs_scanned=scanned if i == 0 else 0,
                 scan_budget=scanned,
@@ -2434,6 +2544,7 @@ class CoaddEngine:
                 matched_cache_hits=m_hits if i == 0 else 0,
                 peak_resident_bytes=self._peak_resident_bytes(),
                 reduce=p.reduce,
+                windowed_packs=int(counts[i, 0]),
             )
             results.append(
                 CoaddResult(np.asarray(coadds[i]), np.asarray(depths[i]), stats)
@@ -2441,7 +2552,8 @@ class CoaddEngine:
         return results
 
     def _execute_batch_streaming(
-        self, plans, exec_ds, gates, qvecs, grids_ra, grids_dec, block_rows
+        self, plans, exec_ds, gates, qvecs, grids_ra, grids_dec, block_rows,
+        gather_only=False,
     ) -> List[CoaddResult]:
         """Windowed batch under a device budget (DESIGN.md §6).
 
@@ -2486,16 +2598,24 @@ class CoaddEngine:
                 grids_dec,
                 use_kernel=self.use_kernel,
                 block_rows=block_rows,
+                window=None if gather_only else self._batch_window_fit(
+                    plans, exec_ds, gates, kern is not None, dev.pixels),
             )
 
         job_key = self._job_key(
             "batch:" + plans[0].method, layout, gates, qvecs, plans[0].npix,
             windows,
-            grid_tag="|".join(self._grid_tag(p) for p in plans),
+            grid_tag="|".join(self._grid_tag(p) for p in plans)
+            + ("|gather" if gather_only else ""),
         )
-        (coadds, depths, contribs, considered), counters, elapsed, fc, quar = \
-            self._run_stream_windows(layout, exec_ds, windows, dispatch,
-                                     job_key)
+        (coadds, depths, contribs, considered, steps), counters, elapsed, \
+            fc, quar = self._run_stream_windows(layout, exec_ds, windows,
+                                                dispatch, job_key)
+        steps = np.asarray(steps)
+        if not _covered(steps):
+            return self._execute_batch_streaming(
+                plans, exec_ds, gates, qvecs, grids_ra, grids_dec, block_rows,
+                gather_only=True)
         uploads, hits, evictions = counters
         # Same coverage honesty as the single path: uncovered = quarantined
         # AND opened by at least one of the batch's gates.
@@ -2520,6 +2640,7 @@ class CoaddEngine:
                 packs_gated=int(gates[i].any(axis=1).sum()),
                 packs_scanned=scanned if i == 0 else 0,
                 scan_budget=max(w.budget for w in windows),
+                windowed_packs=int(steps[i, 0]),
                 windows=len(windows),
                 chunk_uploads=uploads if i == 0 else 0,
                 residency_hits=hits if i == 0 else 0,

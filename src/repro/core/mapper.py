@@ -14,6 +14,21 @@ image's TAN WCS and bilinearly interpolate.  Inverse warping avoids
 scatter — every output pixel is a gather, which is the TPU-friendly
 formulation (scatters serialize; gathers vectorize) and the basis of the
 Pallas kernel in `repro.kernels.warp`.
+
+`bilinear_sample` reads its four taps as element-wise gathers out of the
+whole image (or, in the scan, the whole pack): one scattered HBM word per
+output pixel and tap.  On a TPU the engine's mean scan replaces it, where a
+plan's geometry allows, with the windowed sampler
+(`repro.kernels.warp.windowed`): per output tile of 64x16 pixels it copies
+each frame's small source window (16-48 x 256 pixels, the 256 along the
+frame axis on the lanes; VMEM ~5-10 MB a step) into VMEM once and selects
+the taps there, taking the same coordinates, ``inside`` mask and NaN guard
+as this module.  Its window-fit rule (`windowed.window_fit`) admits a plan
+when every gated frame's footprint over a tile, projected in float64,
+fits the window, and a guard on the device voids any pack step where a
+tap would still fall outside (the engine then redoes the query here);
+otherwise — large rotations, scale mismatches, the CPU backend, the robust
+passes — this gather is the map stage.
 """
 
 from __future__ import annotations

@@ -20,6 +20,7 @@ from repro.kernels.warp.warp import coadd_fused as _coadd_fused
 from repro.kernels.warp.warp import coadd_hist as _coadd_hist
 from repro.kernels.warp.warp import coadd_moments as _coadd_moments
 from repro.kernels.warp.warp import mosaic_bricks as _mosaic_bricks
+from repro.kernels.warp import windowed as _windowed
 
 
 def _rows(block_rows, pixels, grid_ra, psf_kernels):
@@ -80,6 +81,15 @@ def coadd_hist(pixels, wcs_vecs, accepts, grid_ra, grid_dec, lo, inv_w,
         block_rows=_rows(block_rows, pixels, grid_ra, psf_kernels),
         interpret=interpret_mode(),
     )
+
+
+def coadd_windowed(pixels, pack, wcs_vecs, accepts, grid_ra, grid_dec, fit):
+    """Windowed map+reduce of resident pack ``pack`` of (P,cap,H,W) pixels
+    -> (Q,Q) coadd + depth + whether the windows held every tap; ``fit``
+    from `windowed.window_fit`.  Traced inside the caller's scan."""
+    return _windowed.coadd_windowed(pixels, pack, wcs_vecs, accepts, grid_ra,
+                                    grid_dec, fit=fit,
+                                    interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("npix",))

@@ -52,6 +52,15 @@ VMEM budget per grid step: image (H*W*4, double-buffered) + onehot row
 gathers (pixels*H*4) + gathered rows and column masks (pixels*W*4) + pixel
 rows; ``autotune_block_rows`` picks the block and refuses frames that do
 not fit.
+
+These kernels hold a whole frame per grid step and contract over all H
+rows per output pixel (2*H*W multiply-adds, ~6 M at 2048x1489), so SDSS
+frames do not fit them.  The mean scan at such sizes runs the windowed
+sampler of `repro.kernels.warp.windowed` instead, which copies only each
+output tile's source window into VMEM (its own VMEM budget and window-fit
+rule are documented there).  This lane stays whole-frame for now: windowing
+it means the same DMA machinery in four kernel bodies plus a halo for the
+in-kernel PSF convolution, and no benchmark cell runs it yet.
 """
 
 from __future__ import annotations

@@ -152,3 +152,46 @@ def test_resident_scan_compiles_at_sdss_size(one_chip):
     )
     mem = lowered.compile().memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+@pytest.mark.parametrize("hw,lanes", [((2048, 1489), "y"), ((1489, 2048), "x")],
+                         ids=["rows-on-lanes", "published"])
+def test_windowed_scan_compiles_at_sdss_size(one_chip, monkeypatch, hw, lanes):
+    """The benchmark cell's query program on the windowed path: 60 resident
+    packs of 16 SDSS frames, four gated, one 1024^2 output, with frames
+    stored 2048 rows by 1489 columns (as the benchmark's archive holds
+    them) or in the published orientation, 1489 rows by 2048 columns.  XLA
+    lays each out with a 2048-pixel axis on the lanes, which `lane_axis`
+    reads back from the compiled program; the kernel reads that layout
+    through a bitcast, never a copy of it, and the program's working set,
+    the guard's gather fallback included, stays a few MB next to the
+    11.7 GB archive.
+
+    The CPU backend would interpret the kernel: the test turns that off
+    (and drops the traces cached either way) to lower it through Mosaic."""
+    from repro.core.engine import _coadd_scan_sparse
+    from repro.core.seqfile import FLOAT_COLS, META_COLS
+    from repro.kernels.warp import ops, windowed
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    jax.clear_caches()
+
+    def shape(s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    packs, cap, q = 60, 16, 1024
+    fit = windowed.WindowFit(*windowed.TILES[0], 24, windowed.WIN_L, lanes)
+    compiled = _coadd_scan_sparse.lower(
+        shape((packs, cap) + hw), shape((packs, cap, 8)),
+        {k: shape((packs, cap), jnp.int32) for k in META_COLS},
+        {k: shape((packs, cap)) for k in FLOAT_COLS},
+        None, shape((4,), jnp.int32), shape((4, cap), jnp.bool_),
+        shape((7,)), shape((q, q)), shape((q, q)),
+        use_kernel=False, block_rows=None, window=fit,
+    ).compile()
+    jax.clear_caches()
+    assert "tpu_custom_call" in compiled.as_text()
+    m2m = compiled.input_formats[0][0].layout.major_to_minor
+    assert windowed._VIEWS[lanes] == tuple(m2m)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64e6
