@@ -9,10 +9,13 @@ The archive is a seeded synthetic Stripe 82 slice with SDSS's frame shape:
 2048 (Dec) x 1489 (RA) pixels at 0.396"/px, 8 runs x 6 camcols x 5 bands x
 2 fields = 480 frames (5.9 GB of float32 pixels), resident in HBM under the
 structured layout.  Every answer is checked against a float64 numpy
-reference built from the survey frames with `geometry.sky_to_pixel` and
-bilinear sampling, independently of the engine:
+reference built from the survey frames with `geometry.pixel_to_sky`,
+`geometry.sky_to_pixel` and bilinear sampling, independently of the engine:
 
-  a. resident query     one sql_structured r-band query at npix 1024
+  a. resident query     one sql_structured r-band query at npix 1024, and
+                        its output grid as the device builds it
+                        (`mapper.query_grid`) equal bit for bit to the
+                        float64 grid rounded to float32
   b. PSF-matched query  the same query homogenized with the measured stamps
   c. service            a coalesced burst and a repeat through CoaddService
   d. streaming          the same query under a third-of-the-archive budget
@@ -55,7 +58,7 @@ from repro.core import (  # noqa: E402
     make_survey,
     psf,
 )
-from repro.core.mapper import query_grid_sky  # noqa: E402
+from repro.core.mapper import grid_vector, query_grid  # noqa: E402
 
 # Pass criteria against the float64 reference: depth (a coverage count)
 # equal on nearly every pixel — a frame edge sample can flip between
@@ -156,11 +159,21 @@ def _correlate_edge(img, k):
     return full[kh - 1:kh - 1 + img.shape[0], kw - 1:kw - 1 + img.shape[1]]
 
 
+def grid32(query):
+    """The query's output grid: `geometry.pixel_to_sky` of every pixel in
+    float64, rounded to the float32 the engine scans."""
+    n = query.npix
+    xs, ys = np.meshgrid(np.arange(n, dtype=np.float64),
+                         np.arange(n, dtype=np.float64))
+    return [a.astype(np.float32) for a in geometry.pixel_to_sky(
+        xs, ys, query.grid_wcs_vector().astype(np.float64))]
+
+
 def reference(survey, query, psf_target=None):
     """Float64 coadd + depth of the frames of ``query``'s band that overlap
     its box; with ``psf_target`` each frame is first correlated with its
     measured-stamp homogenization kernel."""
-    gr, gd = (a.astype(np.float64) for a in query_grid_sky(query))
+    gr, gd = (a.astype(np.float64) for a in grid32(query))
     coadd = np.zeros(gr.shape)
     depth = np.zeros(gr.shape)
     frames = [im for im in survey.images
@@ -275,9 +288,14 @@ def phase_resident(ctx):
     warm = time.perf_counter() - t0
     require(np.array_equal(res.coadd, again.coadd), "repeat run differs")
     ctx["a"] = res
+    grid = query_grid(grid_vector(q.grid_wcs_vector()), q.npix)
+    grid_diff = sum(int((np.asarray(d) != r).sum())
+                    for d, r in zip(grid, grid32(q)))
+    require(grid_diff == 0, f"device grid differs from the float64 grid "
+            f"rounded to float32 in {grid_diff} coordinates")
     ref_c, ref_d, n = ctx["ref_a"]
     return {"frames": res.stats.files_contributing, "ref_frames": n,
-            "cold_s": cold, "warm_s": warm,
+            "cold_s": cold, "warm_s": warm, "grid_diff": grid_diff,
             **compare(res.coadd, res.depth, ref_c, ref_d)}
 
 

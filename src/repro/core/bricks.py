@@ -13,10 +13,12 @@ The bitwise-parity contract
 Every brick is a tile of ONE global TAN lattice: a single `WCS` anchored at
 the footprint center, ``scale = brick_deg / brick_npix`` deg/px, covering
 ``n_rows x n_cols`` bricks of ``brick_npix`` pixels each.  A brick's output
-grid is computed by running the *global* pixel indices of its tile through
-`pixel_to_sky` in float64 and casting to float32 — the exact arithmetic
-`mapper.query_grid_sky` performs — so the grid of any window of bricks is
-bitwise-identical to the concatenation of its tiles' grids.  Because an
+grid is computed on the host by running the *global* pixel indices of its
+tile through `pixel_to_sky` in float64 and casting to float32 (a query's
+own grid is built on the device instead, `mapper.query_grid`, in
+float-float arithmetic that rounds to the same float32 values at query
+scales), so the grid of any window of bricks
+is bitwise-identical to the concatenation of its tiles' grids.  Because an
 image whose footprint misses a tile contributes *exact zeros* at every tile
 pixel (the masked-discard contract, DESIGN.md §3), and per-pack partials
 accumulate in the same pack/slot order either way, the mosaic of per-brick

@@ -1328,17 +1328,19 @@ class CoaddEngine:
         )
 
     # ----- shared helpers -----
-    def _grids(self, query: CoaddQuery):
-        gr, gd = mapper.query_grid_sky(query)
-        return jnp.asarray(gr), jnp.asarray(gd)
-
     def _plan_grids(self, plan: CoaddPlan):
-        """The plan's output grid: its `grid_sky` override (brick-lattice
-        plans, §9) when present, the query's own TAN grid otherwise."""
+        """The plan's output grid on the device and the bytes its build
+        sent up: ``(grid_ra, grid_dec, h2d_bytes)``.
+
+        The query's own TAN grid is built on the device from its WCS
+        vector (`mapper.query_grid`), so 48 bytes go up; a `grid_sky`
+        override (brick-lattice plans, §9) is the float64 host lattice
+        cast to float32, and is uploaded whole."""
         if plan.grid_sky is not None:
-            gr, gd = plan.grid_sky
-            return jnp.asarray(gr), jnp.asarray(gd)
-        return self._grids(plan.query)
+            gr, gd = (jnp.asarray(a) for a in plan.grid_sky)
+            return gr, gd, _nbytes(gr, gd)
+        gvec = mapper.grid_vector(plan.query.grid_wcs_vector())
+        return (*mapper.query_grid(gvec, plan.query.npix), gvec.nbytes)
 
     @staticmethod
     def _grid_tag(plan: CoaddPlan) -> str:
@@ -1724,7 +1726,7 @@ class CoaddEngine:
             # schedule at all — no upload, no dispatch, and no window-stat
             # reduction over an empty list.
             return self._empty_streaming_result(plan)
-        grid_ra, grid_dec = self._plan_grids(plan)
+        grid_ra, grid_dec, _ = self._plan_grids(plan)
         block_rows = self._block_rows(plan.query, ds)
         windows = self._stream_windows(exec_ds, gate.any(axis=1))
         qvec = jnp.asarray(plan.qvec)
@@ -1836,7 +1838,7 @@ class CoaddEngine:
             res = self._empty_streaming_result(plan)
             res.stats.reduce = plan.reduce
             return res
-        grid_ra, grid_dec = self._plan_grids(plan)
+        grid_ra, grid_dec, _ = self._plan_grids(plan)
         block_rows = self._block_rows(plan.query, ds)
         windows = self._stream_windows(exec_ds, gate.any(axis=1))
         qvec = jnp.asarray(plan.qvec)
@@ -2022,7 +2024,8 @@ class CoaddEngine:
         (`_covered`).  Uploads the layout (and builds the matched-pixel
         cache) on first use, exactly as the dispatch itself would need.
         The per-query operands are built under the ``coadd.execute.grid``
-        and ``coadd.execute.compact`` spans; ``h2d_bytes`` is their size.
+        and ``coadd.execute.compact`` spans; ``h2d_bytes`` is what they
+        send up (48 bytes for a grid built on the device, `_plan_grids`).
         """
         ds = self.dataset(plan.layout)
         # Before the upload: a kernel-lane query whose frames cannot fit
@@ -2036,9 +2039,9 @@ class CoaddEngine:
             # operand, no per-query convolution.
             dev, m_hits = self._matched_device_dataset(plan.layout, dev)
             psf_kernels = None
-        with span("execute.grid") as grid:
-            grid_ra, grid_dec = self._plan_grids(plan)
-            h2d_grid = _nbytes(grid_ra, grid_dec)
+        with span("execute.grid",
+                  on_device=int(plan.grid_sky is None)) as grid:
+            grid_ra, grid_dec, h2d_grid = self._plan_grids(plan)
             grid.set(h2d_bytes=h2d_grid)
         robust = plan.reduce != "mean"
         with span("execute.compact") as compact:
@@ -2857,13 +2860,17 @@ class CoaddEngine:
         exec_ds, _ = self.exec_dataset("structured")
         pad_to = exec_ds.flat_len(n_shards)
         t0 = time.perf_counter()
-        # Per-job host->mesh traffic: gates + qvecs + grids. No pixels.
+        # Per-job host->mesh traffic: gates + qvecs + the grids' WCS
+        # vectors (the grids are built on the device). No pixels.
         gates = np.stack(
             [ds.flat_slot_mask(ids, pad_to=pad_to) for ids in id_sets]
         )
         t_locate += time.perf_counter() - t0
         block_rows = self._block_rows(queries[0], ds)
-        grids = np.stack([np.stack(mapper.query_grid_sky(q)) for q in queries])
+        grids = jnp.stack([
+            jnp.stack(mapper.query_grid(
+                mapper.grid_vector(q.grid_wcs_vector()), q.npix))
+            for q in queries])
         qvecs = np.stack([_query_vec(q) for q in queries])  # (nq, 7)
         nq = len(queries)
 
@@ -3006,7 +3013,7 @@ class CoaddEngine:
                 bud_t,
                 jnp.asarray(gates_exec),
                 jnp.asarray(qvecs),
-                jnp.asarray(grids),
+                grids,
             )
 
         def mesh_window(a: int, b: int) -> MeshResidentDataset:

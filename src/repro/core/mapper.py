@@ -9,9 +9,10 @@ arithmetic cost of discarding is one multiply, matching the paper's
 observation that mapper-side filtering is cheap (§4.1.4).
 
 The projection is an *inverse* warp: for every output pixel we compute its
-sky position once per query, then per image map sky -> source pixel via the
-image's TAN WCS and bilinearly interpolate.  Inverse warping avoids
-scatter — every output pixel is a gather, which is the TPU-friendly
+sky position once per query, on the device from the query's 12-float grid
+vector (`grid_vector`, `query_grid`), then per image map sky -> source
+pixel via the image's TAN WCS and bilinearly interpolate.  Inverse warping
+avoids scatter — every output pixel is a gather, which is the TPU-friendly
 formulation (scatters serialize; gathers vectorize) and the basis of the
 Pallas kernel in `repro.kernels.warp`.
 
@@ -33,26 +34,95 @@ passes — this gather is the map stage.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.geometry import pixel_to_sky, sky_to_pixel
+from repro.core import floatfloat as ff
+from repro.core.geometry import DEG2RAD, RAD2DEG, sky_to_pixel
 from repro.core.query import CoaddQuery
 
 
-def query_grid_sky(query: CoaddQuery) -> Tuple[np.ndarray, np.ndarray]:
-    """Sky coordinates (ra, dec), each (npix, npix), of the output grid.
+_D2R = ff.split_constant(DEG2RAD)
+_R2D = ff.split_constant(RAD2DEG)
 
-    Depends only on the query — computed once per job on the host.
+
+def grid_vector(wcs_vec: np.ndarray) -> np.ndarray:
+    """`query_grid`'s operand, 12 float32 (48 bytes): a TAN grid's 8-float
+    WCS vector, then sin and cos of its tangent point's dec, each computed
+    on the host in float64 and split into a float-float pair."""
+    g = np.asarray(wcs_vec, np.float32)
+    d = np.float64(g[1]) * DEG2RAD
+    trig = [x for f in (np.sin, np.cos) for x in ff.split_constant(f(d))]
+    return np.concatenate([g, np.float32(trig)])
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def query_grid(gvec: jnp.ndarray, npix: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sky coordinates (ra, dec), each (npix, npix) float32 on the device,
+    of the output grid whose `grid_vector` is ``gvec``: the float64 grid of
+    `geometry.pixel_to_sky` rounded to float32, bit for bit.
+
+    The inverse of `geometry.sky_to_tangent`, in a form whose only large
+    operand is the tangent point: with (xi, eta) the pixel's tangent-plane
+    offset in radians and A = cos(dec0) - eta sin(dec0), the point lies
+    along (A, xi, sin(dec0) + eta cos(dec0)), so
+
+        ra  = ra0  + atan(xi / A)                                 * RAD2DEG
+        dec = dec0 + atan((eta - q sin(dec0)) / (1 + q cos(dec0))) * RAD2DEG
+
+    where q = R - A = xi (xi / A) / (1 + R / A) and R = hypot(xi, A).  Each
+    offset is carried in float-float (`floatfloat`, ~46 bits) and added to
+    the tangent point in degrees with a single rounding, so the result is
+    the float64 grid's rounding except where the exact value lies within
+    ~1e-14 deg of a float32 rounding boundary.  Float32 arithmetic misses
+    it by one ulp in ~0.05% of RA pixels (0.035 px at RA 37-40), which a
+    star's wing turns into a 1% error.  The series tail is float32, so a
+    grid degrees across is off by one ulp in a few pixels; pixels more than
+    ``atan(floatfloat.ATAN_SERIES_MAX)`` (14 deg) from the tangent point,
+    or beyond a pole, take float32 `atan2` (`tests/test_query_grid.py`).
+
+    One small program per ``npix``, dispatched on its own: every executor
+    scans the same float32 grid for a query, bit for bit.
     """
-    n = query.npix
-    g = query.grid_wcs_vector().astype(np.float64)
-    xs, ys = np.meshgrid(np.arange(n, dtype=np.float64), np.arange(n, dtype=np.float64))
-    ra, dec = pixel_to_sky(xs, ys, g)
-    return ra.astype(np.float32), dec.astype(np.float32)
+    g = gvec.astype(jnp.float32)
+    ra0, dec0 = g[0], g[1]
+    sin0, cos0 = (g[8], g[9]), (g[10], g[11])
+    pix = jnp.arange(npix, dtype=jnp.float32)
+    dx = ff.two_sum(pix[None, :], -g[2])
+    dy = ff.two_sum(pix[:, None], -g[3])
+
+    def rad(c1, c2):     # (c1 dx + c2 dy) * DEG2RAD
+        return ff.mul(ff.add(ff.mul((c1, 0.0), dx), ff.mul((c2, 0.0), dy)), _D2R)
+
+    xi, eta = rad(g[4], g[5]), rad(g[6], g[7])
+    a = ff.add(cos0, ff.neg(ff.mul(eta, sin0)))
+    u = ff.div(xi, a)
+    w = u[0] * u[0] / (1.0 + jnp.sqrt(1.0 + u[0] * u[0]))   # R / A - 1
+    q = ff.div(ff.mul(xi, u), ff.two_sum(ff.const(2.0), w))
+    n = ff.add(eta, ff.neg(ff.mul(q, sin0)))
+    m = ff.add((ff.const(1.0), ff.const(0.0)), ff.mul(q, cos0))
+    v = ff.div(n, m)
+
+    near = ((a[0] > 0) & (jnp.abs(u[0]) <= ff.ATAN_SERIES_MAX)
+            & (jnp.abs(v[0]) <= ff.ATAN_SERIES_MAX))
+    ra = jnp.where(near, ff.round_sum(ra0, ff.mul(ff.atan(u), _R2D)),
+                   ra0 + jnp.arctan2(xi[0], a[0]) * np.float32(RAD2DEG))
+    dec = jnp.where(near, ff.round_sum(dec0, ff.mul(ff.atan(v), _R2D)),
+                    jnp.arctan2(sin0[0] + eta[0] * cos0[0], jnp.hypot(xi[0], a[0]))
+                    * np.float32(RAD2DEG))
+    return ra, dec
+
+
+def query_grid_sky(query: CoaddQuery) -> Tuple[np.ndarray, np.ndarray]:
+    """Sky coordinates (ra, dec), each (npix, npix) float32, of the output
+    grid: `query_grid` of the query's WCS vector, computed on the device
+    and copied back — the grid the engine scans, bit for bit."""
+    ra, dec = query_grid(grid_vector(query.grid_wcs_vector()), query.npix)
+    return np.asarray(ra), np.asarray(dec)
 
 
 def bilinear_sample(image: jnp.ndarray, sx: jnp.ndarray, sy: jnp.ndarray):

@@ -80,7 +80,9 @@ def test_spans_nest_and_carry_the_counts(engine, reduce, tmp_path, monkeypatch):
 
     st = res.stats
     args = {s[0]: s[3] for s in got}
-    assert args["coadd.execute.grid"]["h2d_bytes"] == 2 * 4 * NPIX**2
+    # The grid is built on the device from the query's 12-float grid vector.
+    assert args["coadd.execute.grid"]["h2d_bytes"] == 12 * 4
+    assert args["coadd.execute.grid"]["on_device"] == 1
     assert (args["coadd.execute.grid"]["h2d_bytes"]
             + args["coadd.execute.compact"]["h2d_bytes"]) == st.h2d_bytes
     assert args["coadd.execute.fetch"]["d2h_bytes"] == st.d2h_bytes

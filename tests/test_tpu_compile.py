@@ -195,3 +195,38 @@ def test_windowed_scan_compiles_at_sdss_size(one_chip, monkeypatch, hw, lanes):
     assert windowed._VIEWS[lanes] == tuple(m2m)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64e6
+
+
+def test_query_grid_compiles_in_the_scans_layout(one_chip, monkeypatch):
+    """The query's output grid program (`mapper.query_grid`) at the
+    benchmark's 1024^2: it compiles for one v5e with a working set of a few
+    hundred KB, and its two outputs leave in the layout in which the
+    windowed scan program takes its grid operands, so the runtime passes
+    them to the scan as they are, with no relayout copy between the two."""
+    from repro.core import mapper
+    from repro.core.engine import _coadd_scan_sparse
+    from repro.core.seqfile import FLOAT_COLS, META_COLS
+    from repro.kernels.warp import ops, windowed
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    jax.clear_caches()
+
+    def shape(s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    packs, cap, q = 60, 16, 1024
+    grid = mapper.query_grid.lower(shape((12,)), q).compile()
+    fit = windowed.WindowFit(*windowed.TILES[0], 24, windowed.WIN_L, "y")
+    scan = _coadd_scan_sparse.lower(
+        shape((packs, cap, 2048, 1489)), shape((packs, cap, 8)),
+        {k: shape((packs, cap), jnp.int32) for k in META_COLS},
+        {k: shape((packs, cap)) for k in FLOAT_COLS},
+        None, shape((4,), jnp.int32), shape((4, cap), jnp.bool_),
+        shape((7,)), shape((q, q)), shape((q, q)),
+        use_kernel=False, block_rows=None, window=fit,
+    ).compile()
+    jax.clear_caches()
+    outs = grid.output_formats
+    assert [o.layout for o in outs] == [f.layout for f in scan.input_formats[0][-2:]]
+    assert all(o.layout.major_to_minor == (0, 1) for o in outs)
+    assert grid.memory_analysis().temp_size_in_bytes < 1e6
